@@ -35,19 +35,18 @@ from jax.sharding import PartitionSpec as P
 
 from benchmarks import common
 from benchmarks.common import emit, time_fn
-from repro import compat
 from repro.configs import registry
 from repro.core import c2c, collectives, hier, hw, planner
+from repro.launch import mesh as mesh_lib
 
 
 def run():
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    mesh = mesh_lib.make_host_mesh()
 
     for n in (1 << 16, 1 << 21):
         x = jax.random.normal(jax.random.PRNGKey(0), (n,), jnp.float32)
         for wire in collectives.WIRES:
-            fn = jax.jit(lambda v, wire=wire: compat.shard_map(
+            fn = jax.jit(lambda v, wire=wire: jax.shard_map(
                 lambda u: collectives.allreduce(u, ("data",), wire=wire),
                 mesh=mesh, in_specs=P(), out_specs=P(),
                 axis_names={"data"}, check_vma=False)(v))
@@ -65,7 +64,7 @@ def run():
          lambda u: collectives.reduce_scatter(u, ("data",))),
         ("all_gather", lambda u: collectives.all_gather(u, ("data",))),
     ):
-        f = jax.jit(lambda v, fn_=fn_: compat.shard_map(
+        f = jax.jit(lambda v, fn_=fn_: jax.shard_map(
             fn_, mesh=mesh, in_specs=P(), out_specs=P(),
             axis_names={"data"}, check_vma=False)(v))
         us = time_fn(f, x)
@@ -77,7 +76,8 @@ def run():
     # a STABLE ledger metric the perf gate can fail on.
     cfg = registry.get_smoke_config("yi-6b")
     batch, seq = 8, 64
-    amesh = compat.abstract_mesh((2, 4), (hier.NODE_AXIS, hier.LOCAL_AXIS))
+    amesh = jax.sharding.AbstractMesh((2, 4),
+                                      (hier.NODE_AXIS, hier.LOCAL_AXIS))
     plan = planner.plan_hybrid(cfg, amesh, batch=batch, seq=seq)
     specs = c2c.layers_from_model_config(cfg, seq)
     for topo in (hw.CLOUD_10G, hw.HPC_OPA):
@@ -101,7 +101,7 @@ def run_hier():
              f"needs 8 virtual devices, have {n_dev}")
         return
     node, local = 2, 4
-    mesh = compat.make_mesh((node, local), (hier.NODE_AXIS, hier.LOCAL_AXIS))
+    mesh = jax.make_mesh((node, local), (hier.NODE_AXIS, hier.LOCAL_AXIS))
     dspec = P((hier.NODE_AXIS, hier.LOCAL_AXIS))
 
     configs = (
@@ -124,8 +124,8 @@ def run_hier():
                 inner = lambda u, s=spec: hier.hier_allreduce(  # noqa: E731
                     u[0], s)
                 wb = hier.hier_wire_bytes_per_elem(spec, local, node)
-            fn = jax.jit(compat.shard_map(inner, mesh=mesh, in_specs=dspec,
-                                          out_specs=P()))
+            fn = jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=dspec,
+                                       out_specs=P(), check_vma=False))
             us = time_fn(fn, x)
             emit(f"collectives/hier_sweep/{name}/n{n}", us,
                  f"wire_B_per_elem_total={wb.total:.3f};"
@@ -153,7 +153,6 @@ def run_hybrid():
              f"needs 8 virtual devices, have {n_dev}")
         return
     from repro.data import pipeline
-    from repro.launch import mesh as mesh_lib
     from repro.models.transformer import Batch, Model
     from repro.optim import optimizers as opt_lib
     from repro.train import trainer as tr
@@ -169,7 +168,7 @@ def run_hybrid():
     b = Batch(tokens=jnp.asarray(raw["tokens"]),
               labels=jnp.asarray(raw["labels"]))
     results = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name, plnr in (
             ("dp", planner.Planner(mesh=mesh)),
             ("hybrid", planner.make_hybrid_planner(mesh, cfg, batch=batch,

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 
 from benchmarks import common
 from benchmarks.common import emit, fmt_exposed, reduction_ratio, time_fn
-from repro import compat
 from repro.core import hw
 from repro.core import planner as planner_lib
 from repro.core.planner import Planner
@@ -67,7 +66,7 @@ SEQ = 32
 
 def _step_us(model, opt, mesh, pln, comm, batch, iters):
     """Median per-step wall time (us) of a compiled train step."""
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
         step = jax.jit(tr.make_train_step(model, opt, mesh, pln, comm))
         return time_fn(lambda: step(state, batch)[1]["loss"], iters=iters)
@@ -122,9 +121,7 @@ def run(smoke: bool = False):
             # modeled-only fallback: a representative smoke-size plan
             cfg = registry.get_smoke_config(ARCH)
             model = Model(cfg)
-            mesh11 = compat.make_mesh(
-                (1, 1), ("data", "model"),
-                axis_types=(compat.AxisType.Auto,) * 2)
+            mesh11 = mesh_lib.make_host_mesh()
             engine = tr.make_comm_engine(
                 model, mesh11, Planner(mesh=mesh11),
                 tr.CommConfig(mode="mlsl", accum_steps=acc, overlap=True))

@@ -72,7 +72,8 @@ def run(smoke: bool = False):
     for n in (1 << 16,) if smoke else (1 << 16, 1 << 20):
         x = jax.random.normal(key, (n,))
         us_jnp = time_fn(lambda x=x: kops.quantize(x, backend="jnp")[0])
-        us_pal = time_fn(lambda x=x: kops.quantize(x, backend="pallas")[0])
+        pal = kops.wire_backend("pallas")
+        us_pal = time_fn(lambda x=x: kops.quantize(x, backend=pal)[0])
         emit(f"quantization/kernel_n{n}", us_pal,
              f"jnp_us={us_jnp:.1f};pallas_interpret_us={us_pal:.1f}")
 

@@ -22,7 +22,6 @@ if _FLAG not in os.environ.get("XLA_FLAGS", ""):
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import cnn_tables, registry
 from repro.core import c2c, hw, planner as pl, simulator as sim
 from repro.data import pipeline
@@ -59,7 +58,7 @@ def main():
     print("\n=== 3. executed hybrid plan (yi-6b smoke, node=2 x local=4) ===")
     cfg = registry.get_smoke_config("yi-6b")
     batch, seq = 8, 64
-    amesh = compat.abstract_mesh((2, 4), ("node", "local"))
+    amesh = jax.sharding.AbstractMesh((2, 4), ("node", "local"))
     plan = pl.plan_hybrid(cfg, amesh, batch=batch, seq=seq)
     for lp in plan.layers:
         note = f" [{lp.reason}]" if lp.reason else ""
@@ -91,7 +90,7 @@ def main():
     optimizer = opt_lib.make_optimizer("adamw", 3e-3)
     dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=seq,
                                global_batch=batch, seed=0)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.make_train_state(model, optimizer, jax.random.PRNGKey(0))
         step_fn = jax.jit(tr.make_train_step(model, optimizer, mesh, planner,
                                              comm))

@@ -8,10 +8,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import registry
 from repro.core.api import Session
 from repro.data import pipeline
+from repro.launch import mesh as mesh_lib
 from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib
 from repro.serve.engine import Engine, EngineConfig
@@ -25,8 +25,7 @@ def main():
     print(f"model: {cfg.name}  params: {model.n_params():,}")
 
     # 2. a Session = mesh + planner + MLSL comm config (paper C7)
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    mesh = mesh_lib.make_host_mesh()
     sess = Session.create(
         mesh, n_params=model.n_params(),
         comm=tr.CommConfig(mode="mlsl", wire="bf16", prioritize=True))
@@ -35,7 +34,7 @@ def main():
     # 3. train
     opt = opt_lib.adamw(3e-3)
     data = pipeline.DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=8)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
         step = jax.jit(sess.make_train_step(model, opt))
         for i, raw in enumerate(pipeline.iterate(data, 40)):

@@ -16,11 +16,11 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.checkpoint import ckpt
 from repro.configs.base import AttnConfig, ModelConfig
 from repro.core.planner import Planner
 from repro.data import pipeline
+from repro.launch import mesh as mesh_lib
 from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib, schedules
 from repro.train import trainer as tr
@@ -61,8 +61,7 @@ def main():
     steps = args.steps or p["steps"]
     cfg = build_config(p)
     model = Model(cfg)
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    mesh = mesh_lib.make_host_mesh()
     planner = Planner(mesh=mesh)
     lr = schedules.warmup_cosine(3e-3, steps // 10, steps)
     opt = opt_lib.adamw(lr)
@@ -73,9 +72,13 @@ def main():
                                global_batch=p["batch"])
     print(f"preset={args.preset} params={model.n_params():,} "
           f"comm={args.comm}/{args.wire} steps={steps}")
-    with compat.set_mesh(mesh):
-        state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
-        step = jax.jit(tr.make_train_step(model, opt, mesh, planner, comm))
+    engine = (tr.make_comm_engine(model, mesh, planner, comm)
+              if comm.mode == "mlsl" else None)
+    with jax.set_mesh(mesh):
+        state = tr.make_train_state(model, opt, jax.random.PRNGKey(0),
+                                    engine=engine)
+        step = jax.jit(tr.make_train_step(model, opt, mesh, planner, comm,
+                                          engine=engine))
         t0 = time.time()
         for i, raw in enumerate(pipeline.iterate(data, steps)):
             batch = Batch(tokens=jnp.asarray(raw["tokens"]),

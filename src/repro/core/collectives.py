@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.kernels import ops as kops
 
 WIRE_FP32 = "fp32"
@@ -59,7 +58,7 @@ def _axes_tuple(axes) -> tuple:
 
 def axis_size(axes) -> int:
     """Product of the manual-axis sizes (callable inside shard_map)."""
-    return compat.axis_size(_axes_tuple(axes))
+    return jax.lax.axis_size(_axes_tuple(axes))
 
 
 def _pad_flat(flat: jax.Array, quantum: int) -> jax.Array:
@@ -257,8 +256,8 @@ def broadcast(x: jax.Array, axes, *, root: int = 0) -> jax.Array:
 # gradient replicated across the model group while weights stay sharded.
 #
 # Both directions are written out explicitly via custom_vjp: inside the
-# fully-manual shard_map regions this repo uses (check_vma=False, JAX
-# 0.4.30+), the built-in transpose of a bare lax.psum does NOT produce the
+# fully-manual shard_map regions this repo uses (check_vma=False), the
+# built-in transpose of a bare lax.psum does NOT produce the
 # replicated-input gradient this pattern needs (tests/test_hybrid.py pins
 # the correct values against a dense single-rank reference).
 
@@ -381,9 +380,9 @@ class Comm:
             extra_manual_axes: Sequence[str] = ()):
         """Run `fn` manually over the data axes (model axis stays GSPMD)."""
         manual = set(self.data_axes) | set(extra_manual_axes)
-        wrapped = compat.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                   out_specs=out_specs, axis_names=manual,
-                                   check_vma=False)
+        wrapped = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                out_specs=out_specs, axis_names=manual,
+                                check_vma=False)
         return wrapped(*args)
 
     @property

@@ -71,8 +71,9 @@ class CommConfig:
     # the engine falls back to the single reduce-at-end exchange.
     overlap: bool = False
     # int8 wire kernel dispatch: "auto" resolves through the single
-    # kernels/ops.py policy (pallas on TPU, jnp/interpret-validated pallas
-    # elsewhere); the resolved choice is recorded in EnginePlan.quant_backend.
+    # kernels/ops.py policy for the mesh's platform (compiled pallas on TPU,
+    # jnp/interpreted pallas elsewhere); the resolved choice is recorded in
+    # EnginePlan.quant_backend.
     # `fused_quant=False` falls back to the composed (multi-pass) kernels --
     # an ablation/debug path, not a production setting.
     quant_backend: str = "auto"
@@ -116,7 +117,7 @@ class EnginePlan:
     tp: int = 1
     bucket_axes: tuple = ()
     # int8 wire execution detail, resolved once at plan-build time: which
-    # kernel backend every quantized leg runs ("pallas" | "jnp"), whether the
+    # kernel backend every quantized leg runs (kops.BACKENDS), whether the
     # single-pass fused kernels are used, and the per-bucket padding waste
     # fraction the (TILE_ROWS x QUANT_BLOCK) tiling charges (only non-trivial
     # for tiny buckets; () when the wire is not int8).
@@ -189,10 +190,13 @@ def build_plan(grad_struct, comm: CommConfig, mesh, data_axes, *,
     for a in data_axes:
         dp *= mesh.shape[a]
     use_ef = comm.error_feedback and comm.wire == cl.WIRE_INT8
-    # resolve the kernel backend ONCE here (the plan records the choice; the
-    # traced data path never consults the policy again) and account the
-    # tiling pad waste per bucket so undersized int8 buckets are visible
-    qb = kops.wire_backend(comm.quant_backend)
+    # resolve the kernel backend ONCE here, for the platform of the mesh's
+    # devices (the plan records the choice; the traced data path never
+    # consults the policy again) and account the tiling pad waste per
+    # bucket so undersized int8 buckets are visible
+    platform = (mesh.devices.flat[0].platform
+                if isinstance(mesh, jax.sharding.Mesh) else None)
+    qb = kops.wire_backend(comm.quant_backend, platform)
     quant_pad = ()
     if comm.wire == cl.WIRE_INT8:
         quant_pad = tuple(kops.pad_info(b.n_elems).waste_frac
@@ -304,8 +308,12 @@ class CommEngine:
         return self.plan.use_ef and self.plan.fusable[bi]
 
     def init_residuals(self):
-        """Global-view zero residuals: per-rank shard shape x dp ranks (the
-        shard_map in_spec splits them back to one fabric shard per rank).
+        """Global-view zero residuals: per-rank shard x dp ranks along dim 0
+        (the shard_map in_spec splits them back to one fabric shard per
+        rank). Each shard is held in the wire kernels' (blocks, QUANT_BLOCK)
+        layout, so the fused error-feedback kernel updates it in place: a
+        flat copy would cost a relayout into and out of that layout, two
+        residual-sized temporaries per bucket.
 
         Only buckets whose data path applies error feedback (fusable ones —
         see `ef_applied`) get real buffers; the rest hold zero-length
@@ -315,16 +323,18 @@ class CommEngine:
         if not p.use_ef:
             return None
 
-        def shard(bi, b):
+        def init(bi, b):
             if not self.ef_applied(bi):
-                return 0
+                return jnp.zeros((0,), jnp.float32)
             if p.algos[bi] == planner_lib.ALGO_HIER:
-                return hier_lib.ef_residual_shape(b.n_elems, p.n_local,
-                                                  p.n_node)[0]
-            return cl.ef_residual_shape(b.n_elems, p.dp)[0]
+                n = hier_lib.ef_residual_shape(b.n_elems, p.n_local,
+                                               p.n_node)[0]
+            else:
+                n = cl.ef_residual_shape(b.n_elems, p.dp)[0]
+            return jnp.zeros((n // cl.QUANT_BLOCK * p.dp, cl.QUANT_BLOCK),
+                             jnp.float32)
 
-        return tuple(jnp.zeros((shard(bi, b) * p.dp,), jnp.float32)
-                     for bi, b in enumerate(p.buckets.buckets))
+        return tuple(init(bi, b) for bi, b in enumerate(p.buckets.buckets))
 
     def residual_specs(self, bucket_spec):
         """shard_map in/out specs for the residual state (None without EF)."""

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import collectives as cl
+from repro.kernels import ops as kops
 
 NODE_AXIS = "node"      # inter-node (fabric) mesh axis
 LOCAL_AXIS = "local"    # intra-node (high-bandwidth) mesh axis
@@ -65,7 +66,7 @@ class HierSpec:
             raise ValueError(self.wire_inter)
         if self.error_feedback and self.wire_inter != cl.WIRE_INT8:
             raise ValueError("error feedback requires the int8 fabric leg")
-        if self.backend not in ("auto", "pallas", "jnp"):
+        if self.backend != "auto" and self.backend not in kops.BACKENDS:
             raise ValueError(
                 f"unknown quantization backend {self.backend!r}")
 

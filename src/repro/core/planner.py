@@ -110,7 +110,10 @@ class Planner:
         kind = pd.kind
 
         def try_model(cands):
-            if self.dp_only or not model_ok:
+            # a model axis of size 1 shards nothing: leaving the dim
+            # unsharded keeps the leaf replicated, so the explicit data path
+            # may fuse its gradient into flat (int8-wire) buckets
+            if self.dp_only or not model_ok or self.model_size == 1:
                 return None
             for d in cands:
                 if _divides(shape[d], self.model_size):

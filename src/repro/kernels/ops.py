@@ -8,14 +8,20 @@ a materialized f32 copy.
 
 Backend policy (`wire_backend`, the single policy every comm call site
 resolves through -- repro.core.collectives/hier take a ``backend`` argument
-and the CommEngine records the resolved choice in its EnginePlan):
+and the CommEngine resolves it once, against the platform of its mesh, and
+records the choice in its EnginePlan):
 
-  * "pallas"  -- pl.pallas_call (compiled on TPU; interpret=True elsewhere,
-                 which validates the kernels but is far slower than XLA).
-  * "jnp"     -- the pure-jnp oracle (identical math; used inside GSPMD-
-                 partitioned regions and as the CPU default).
-  * "auto"    -- pallas on TPU; elsewhere the REPRO_QUANT_BACKEND env var
-                 ("pallas" runs the interpret-validated kernels) or jnp.
+  * "pallas"    -- pl.pallas_call compiled by Mosaic (TPU targets only).
+  * "interpret" -- the same kernels under the Pallas interpreter; validates
+                   them on CPU but is far slower than XLA.
+  * "jnp"       -- the pure-jnp oracle (identical math; used inside GSPMD-
+                   partitioned regions and as the CPU default).
+  * "auto"      -- resolved by `wire_backend`: the compiled kernels on a TPU
+                   target; elsewhere the REPRO_QUANT_BACKEND env var
+                   ("pallas" runs the interpreted kernels) or jnp.
+
+A TPU target always gets the compiled kernels: neither the oracle nor the
+interpreter is ever chosen for it.
 """
 
 from __future__ import annotations
@@ -64,27 +70,41 @@ def pad_info(n: int, block: int = quant8.DEFAULT_BLOCK) -> PadInfo:
     return PadInfo(n=n, padded=padded, waste_elems=padded - n)
 
 
-def wire_backend(requested: str = "auto") -> str:
-    """Resolve a requested backend against the single dispatch policy:
-    pallas on TPU, interpret-validated pallas (REPRO_QUANT_BACKEND=pallas)
-    or the jnp oracle elsewhere. Explicit requests pass through."""
-    if requested != "auto":
-        if requested not in ("pallas", "jnp"):
+BACKENDS = ("pallas", "interpret", "jnp")
+
+
+def wire_backend(requested: str = "auto", platform: str | None = None) -> str:
+    """Resolve a requested backend for computations on `platform` devices
+    (default: the platform of JAX's default device).
+
+    A TPU target runs the compiled kernels ("auto" and "pallas" resolve to
+    "pallas"; asking for the oracle or the interpreter there is an error).
+    Elsewhere "pallas" means the interpreted kernels, and "auto" follows
+    REPRO_QUANT_BACKEND (default jnp)."""
+    if requested != "auto" and requested not in BACKENDS:
+        raise ValueError(
+            f"unknown quantization backend {requested!r}; expected "
+            f"'auto' or one of {BACKENDS}")
+    if platform is None:
+        platform = jax.devices()[0].platform
+    if platform == "tpu":
+        if requested in ("jnp", "interpret"):
             raise ValueError(
-                f"unknown quantization backend {requested!r}; expected "
-                f"'auto', 'pallas' or 'jnp'")
-        return requested
-    if jax.default_backend() == "tpu":
+                f"quantization backend {requested!r} on a TPU target: the "
+                f"int8 wire there runs the compiled kernels")
         return "pallas"
-    env = os.environ.get("REPRO_QUANT_BACKEND", "jnp")
-    return env if env in ("pallas", "jnp") else "jnp"
+    if requested == "auto":
+        requested = os.environ.get("REPRO_QUANT_BACKEND", "jnp")
+        if requested not in ("pallas", "jnp"):
+            requested = "jnp"
+    return "interpret" if requested == "pallas" else requested
 
 
-_backend = wire_backend      # internal alias (pre-policy spelling)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _resolve(backend: str) -> str:
+    """Direct callers may pass "auto"; a resolved name is taken as given so
+    a plan resolved for a TPU mesh keeps the compiled kernels even where
+    the host's default device is a CPU (a compile for a described chip)."""
+    return backend if backend in BACKENDS else wire_backend(backend)
 
 
 def _to_blocks(x: jax.Array, block: int, *, pad_to: int | None = None):
@@ -106,10 +126,11 @@ def quantize(x: jax.Array, *, block: int = quant8.DEFAULT_BLOCK,
              backend: str = "auto"):
     """x (any shape, any float dtype) -> (q int8 (n_blocks, block), scales
     f32, QuantMeta). The cast to f32 happens inside the kernel/oracle."""
-    be = wire_backend(backend)
+    be = _resolve(backend)
     x2d, n = _to_blocks(x, block)
-    if be == "pallas":
-        q, s = quant8.quantize_cast_blocks(x2d, interpret=_interpret())
+    if be != "jnp":
+        q, s = quant8.quantize_cast_blocks(x2d,
+                                           interpret=be == "interpret")
     else:
         q, s = ref.quantize_blocks(x2d)
     meta = QuantMeta(shape=tuple(x.shape), dtype=x.dtype, n=n, block=block)
@@ -128,16 +149,16 @@ def quantize_ef(x: jax.Array, residual: jax.Array, *,
     jnp and (interpret-mode) pallas stay aligned and the jnp path is bitwise
     equal to composing quantize + dequantize_accumulate by hand.
     """
-    be = wire_backend(backend)
+    be = _resolve(backend)
     x2d, n = _to_blocks(x, block)
     r2d, rn = _to_blocks(residual.astype(jnp.float32), block,
                          pad_to=x2d.size)
     if rn != n:
         raise ValueError(
             f"residual has {rn} elements but the input has {n}")
-    if be == "pallas":
+    if be != "jnp":
         q, s, nr = quant8.quantize_ef_blocks(x2d, r2d,
-                                             interpret=_interpret())
+                                             interpret=be == "interpret")
     else:
         q, s, nr = ref.quantize_ef_blocks(x2d, r2d)
     meta = QuantMeta(shape=tuple(x.shape), dtype=x.dtype, n=n, block=block)
@@ -147,10 +168,10 @@ def quantize_ef(x: jax.Array, residual: jax.Array, *,
 
 def dequantize(q: jax.Array, scales: jax.Array, meta: QuantMeta, *,
                backend: str = "auto") -> jax.Array:
-    be = wire_backend(backend)
-    if be == "pallas":
+    be = _resolve(backend)
+    if be != "jnp":
         x2d = quant8.dequantize_blocks(q, scales, out_dtype=jnp.float32,
-                                       interpret=_interpret())
+                                       interpret=be == "interpret")
     else:
         x2d = ref.dequantize_blocks(q, scales, out_dtype=jnp.float32)
     flat = x2d.reshape(-1)[: meta.n]
@@ -166,11 +187,12 @@ def dequantize_accumulate(q: jax.Array, scales: jax.Array, acc: jax.Array,
     accumulator. The result keeps ACC's dtype (accumulators stay f32 even
     when the quantized tensor was a bf16 wire buffer), reshaped to
     meta.shape."""
-    be = wire_backend(backend)
+    be = _resolve(backend)
     acc2d, _ = _to_blocks(acc, meta.block, pad_to=q.size)
-    if be == "pallas":
+    if be != "jnp":
         x2d = quant8.dequantize_accumulate_blocks(
-            q, scales, acc2d, out_dtype=jnp.float32, interpret=_interpret())
+            q, scales, acc2d, out_dtype=jnp.float32,
+            interpret=be == "interpret")
     else:
         x2d = ref.dequantize_accumulate_blocks(q, scales, acc2d,
                                                out_dtype=jnp.float32)

@@ -39,6 +39,12 @@ LANE = 128
 DEFAULT_BLOCK = 512          # elements per quantization block (multiple of 128)
 TILE_ROWS = 8                # quantization blocks handled per grid step
 
+# Mosaic takes a rank-1 block only when it spans a multiple of 128 elements,
+# so inside the kernels the per-block scales are an (n_blocks, 1) column
+# tiled (TILE_ROWS, 1). The jitted wrappers below reshape at their boundary:
+# callers see (n_blocks,) scales.
+_SCALE_SPEC = pl.BlockSpec((TILE_ROWS, 1), lambda i: (i, 0))
+
 
 def _quantize_kernel(x_ref, q_ref, s_ref):
     """One tile: (TILE_ROWS, block) float -> int8 + per-row scale.
@@ -46,12 +52,12 @@ def _quantize_kernel(x_ref, q_ref, s_ref):
     The input cast to f32 happens on the VMEM tile, so a bf16 wire buffer is
     consumed directly (no materialized f32 copy in HBM)."""
     x = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x), axis=1)                   # (rows,)
+    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)    # (rows, 1)
     scale = amax / 127.0
     safe = jnp.where(scale > 0.0, scale, 1.0)
-    q = jnp.clip(jnp.round(x / safe[:, None]), -127, 127)
+    q = jnp.clip(jnp.round(x / safe), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale.astype(jnp.float32)
+    s_ref[...] = scale
 
 
 def _quantize_ef_kernel(x_ref, r_ref, q_ref, s_ref, nr_ref):
@@ -65,27 +71,25 @@ def _quantize_ef_kernel(x_ref, r_ref, q_ref, s_ref, nr_ref):
     (3-4 HBM round-trips in collectives.allreduce_ef) reads x and residual
     once and writes q, scale, new_residual once."""
     y = x_ref[...].astype(jnp.float32) + r_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(y), axis=1)
+    amax = jnp.max(jnp.abs(y), axis=1, keepdims=True)
     scale = amax / 127.0
     safe = jnp.where(scale > 0.0, scale, 1.0)
-    q = jnp.clip(jnp.round(y / safe[:, None]), -127, 127)
+    q = jnp.clip(jnp.round(y / safe), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale.astype(jnp.float32)
-    nr_ref[...] = y - q * scale[:, None]
+    s_ref[...] = scale
+    nr_ref[...] = y - q * scale
 
 
 def _dequantize_kernel(q_ref, s_ref, o_ref, *, out_dtype):
     q = q_ref[...].astype(jnp.float32)
-    s = s_ref[...].astype(jnp.float32)
-    o_ref[...] = (q * s[:, None]).astype(out_dtype)
+    o_ref[...] = (q * s_ref[...]).astype(out_dtype)
 
 
 def _dequant_accum_kernel(q_ref, s_ref, acc_ref, o_ref, *, out_dtype):
     """Fused dequantize + accumulate: o = acc + q * s (error-feedback path)."""
     q = q_ref[...].astype(jnp.float32)
-    s = s_ref[...].astype(jnp.float32)
     acc = acc_ref[...].astype(jnp.float32)
-    o_ref[...] = (acc + q * s[:, None]).astype(out_dtype)
+    o_ref[...] = (acc + q * s_ref[...]).astype(out_dtype)
 
 
 def _grid(n_blocks: int) -> tuple:
@@ -117,20 +121,21 @@ def quantize_blocks(x2d: jax.Array, *, interpret: bool = False):
     """
     n_blocks, block = x2d.shape
     _check_block(x2d.shape)
-    return pl.pallas_call(
+    q, s = pl.pallas_call(
         _quantize_kernel,
         grid=_grid(n_blocks),
         in_specs=[pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS,), lambda i: (i,)),
+            _SCALE_SPEC,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_blocks, block), jnp.int8),
-            jax.ShapeDtypeStruct((n_blocks,), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x2d)
+    return q, s.reshape(n_blocks)
 
 
 # The wire cast is folded into the quantize tile (`_quantize_kernel` casts on
@@ -148,6 +153,8 @@ def quantize_ef_blocks(x2d: jax.Array, res2d: jax.Array, *,
     res2d: (n_blocks, block) f32 residual carried from the previous step.
     Returns (q int8, scales f32 (n_blocks,), new_residual f32) where
     q/scales quantize ``x + res`` and ``new_residual = x + res - q * s``.
+    The new residual takes res2d's buffer (updated in place when the caller
+    donates it).
     """
     n_blocks, block = x2d.shape
     _check_block(x2d.shape)
@@ -155,7 +162,7 @@ def quantize_ef_blocks(x2d: jax.Array, res2d: jax.Array, *,
         raise ValueError(
             f"residual shape {res2d.shape} must match the blocked input "
             f"shape {x2d.shape}")
-    return pl.pallas_call(
+    q, s, nr = pl.pallas_call(
         _quantize_ef_kernel,
         grid=_grid(n_blocks),
         in_specs=[
@@ -164,16 +171,18 @@ def quantize_ef_blocks(x2d: jax.Array, res2d: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS,), lambda i: (i,)),
+            _SCALE_SPEC,
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_blocks, block), jnp.int8),
-            jax.ShapeDtypeStruct((n_blocks,), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
             jax.ShapeDtypeStruct((n_blocks, block), jnp.float32),
         ],
+        input_output_aliases={1: 2},
         interpret=interpret,
     )(x2d, res2d)
+    return q, s.reshape(n_blocks), nr
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -186,12 +195,12 @@ def dequantize_blocks(q2d: jax.Array, scales: jax.Array, *,
         grid=_grid(n_blocks),
         in_specs=[
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS,), lambda i: (i,)),
+            _SCALE_SPEC,
         ],
         out_specs=pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), out_dtype),
         interpret=interpret,
-    )(q2d, scales)
+    )(q2d, scales.astype(jnp.float32).reshape(n_blocks, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -205,10 +214,10 @@ def dequantize_accumulate_blocks(q2d: jax.Array, scales: jax.Array,
         grid=_grid(n_blocks),
         in_specs=[
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS,), lambda i: (i,)),
+            _SCALE_SPEC,
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), out_dtype),
         interpret=interpret,
-    )(q2d, scales, acc)
+    )(q2d, scales.astype(jnp.float32).reshape(n_blocks, 1), acc)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) combination against the production mesh, with 512 placeholder host
 devices standing in for the chips (no real allocation: all inputs are
@@ -23,6 +20,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Optional
@@ -31,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import registry
 from repro.configs.base import (ModelConfig, active_param_count_estimate,
                                 param_count_estimate)
@@ -318,8 +315,8 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     if minipod:
         # 64-chip (8, 8) analysis mesh: used for wire-format studies where
         # XLA:CPU cannot compile the manual-mode pattern at 512 partitions
-        mesh = compat.make_mesh((8, 8), ("data", "model"),
-                                axis_types=(compat.AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((8, 8), ("data", "model"),
+                                axis_types=(jax.sharding.AxisType.Auto,) * 2)
         mesh_name = "minipod8x8"
     else:
         mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
@@ -396,7 +393,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         "temp_bytes": int(ma.temp_size_in_bytes),
         "generated_code_bytes": int(ma.generated_code_size_in_bytes),
     }
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     cost_full = {k: float(ca.get(k, 0.0)) for k in ("flops", "bytes accessed")}
     rec["cost_full"] = cost_full
 
@@ -406,7 +403,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         bfn, bargs = build_block_step(cfg, shape, mesh, planner, comm,
                                       shape.kind)
         bcompiled = jax.jit(bfn).lower(*bargs).compile()
-        bca = compat.cost_analysis(bcompiled)
+        bca = bcompiled.cost_analysis() or {}
         cost_block = {k: float(bca.get(k, 0.0))
                       for k in ("flops", "bytes accessed")}
         rec["cost_block"] = cost_block
@@ -423,6 +420,11 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    # the 512 placeholder host devices (last in the flags, so it wins over
+    # an inherited count); read when the first device query starts the CPU
+    # backend, so this runs before anything touches a device
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=registry.ARCH_IDS)
     ap.add_argument("--shape", choices=sorted(SHAPES))

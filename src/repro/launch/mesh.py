@@ -18,21 +18,25 @@ the 16 (or 2x16) groups.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro import compat
+
+def _auto_mesh(shape: tuple, axes: tuple) -> jax.sharding.Mesh:
+    """Every axis Auto (jax.make_mesh defaults to Explicit): the partitioner
+    places what the step leaves open, and shard_map regions pick their
+    manual axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes,
-                            axis_types=(compat.AxisType.Auto,) * len(axes))
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over however many (possibly fake) devices exist locally."""
-    return compat.make_mesh((data, model), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_hier_mesh(node: int = 2, local: int = 4,
@@ -44,11 +48,8 @@ def make_hier_mesh(node: int = 2, local: int = 4,
     ("node", "local"). ``model=1`` keeps a model axis for hybrid plans.
     """
     if model > 1:
-        return compat.make_mesh((node, local, model),
-                                ("node", "local", "model"),
-                                axis_types=(compat.AxisType.Auto,) * 3)
-    return compat.make_mesh((node, local), ("node", "local"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+        return _auto_mesh((node, local, model), ("node", "local", "model"))
+    return _auto_mesh((node, local), ("node", "local"))
 
 
 def n_chips(mesh: jax.sharding.Mesh) -> int:

@@ -1,28 +1,33 @@
 """Training driver.
 
-Runs real training on whatever devices exist (CPU here; the same code path
-drives TPU meshes), with the MLSL comm stack selectable from the CLI:
+Trains on JAX's devices (TPU chips, or CPU devices for tests and CI), with
+the MLSL comm stack selectable from the CLI:
 
   PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \
       --steps 50 --comm mlsl --wire int8 --batch 8 --seq 64
 
 --smoke uses the reduced config of the same family; full configs are for
-real hardware (the dry-run covers them at mesh scale).
+real hardware (the dry-run covers them at mesh scale). `run` does the CLI's
+work for any ModelConfig (chip_smoke.py calls it at published widths).
 """
 
 from __future__ import annotations
 
 import argparse
-import json
+import dataclasses
+import itertools
 import os
+import pathlib
 import time
+from typing import Any
 
 import jax
-import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.checkpoint import ckpt
 from repro.configs import registry
+from repro.configs.base import ModelConfig
 from repro.core import planner as pl
 from repro.data import pipeline
 from repro.launch import mesh as mesh_lib
@@ -31,7 +36,20 @@ from repro.optim import optimizers as opt_lib, schedules
 from repro.train import trainer as tr
 
 
-def main():
+# the persistent compile cache's home when JAX_COMPILATION_CACHE_DIR is not
+# set: fixed, because the path is part of what a later run looks up
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> None:
+    """Keep compiled programs across runs: in $JAX_COMPILATION_CACHE_DIR
+    when it is set (JAX reads it itself), otherwise in CACHE_DIR inside the
+    checkout. Call at start-up, before the first compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=registry.ARCH_IDS, default="yi-6b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -94,10 +112,35 @@ def main():
                     metavar="N",
                     help="bucket-replay sampling period in steps for "
                          "--telemetry (default 25; 0 disables the replay)")
-    args = ap.parse_args()
+    return ap
 
+
+@dataclasses.dataclass
+class RunResult:
+    state: Any                # the final TrainState (the step donates it)
+    history: list             # (step, loss, grad_norm, t) at logged steps;
+                              # t = host clock once that step's result is in
+    compiled: Any             # the step's compiled executable
+    compile_s: float          # lower + compile of the step (cache included)
+    n_compiles: int           # executables the jitted step holds (1)
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
     cfg = (registry.get_smoke_config(args.arch) if args.smoke
            else registry.get_config(args.arch))
+    res = run(cfg, args)
+    if args.ckpt_dir:
+        ckpt.save(args.ckpt_dir, {"params": res.state.params},
+                  step=args.steps)
+        print(f"checkpoint -> {args.ckpt_dir}")
+    return 0
+
+
+def run(cfg: ModelConfig, args: argparse.Namespace) -> RunResult:
+    """Set up mesh, planner, comm engine, state and data for `cfg` as the
+    CLI `args` say, then train `args.steps` steps."""
     model = Model(cfg)
     if args.hybrid:
         if args.comm != "mlsl":
@@ -131,6 +174,8 @@ def main():
                          overlap=args.overlap)
     dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                                global_batch=args.batch, seed=args.seed)
+    engine = (tr.make_comm_engine(model, mesh, planner, comm)
+              if args.comm == "mlsl" else None)
 
     meter = tracer = None
     if args.stats or args.trace or args.telemetry:
@@ -142,7 +187,7 @@ def main():
             tracer.name_process(0, "measured")
             tracer.name_thread(0, 0, "train steps")
 
-    telem = monitor = timer = tel_engine = None
+    telem = monitor = timer = None
     t_model_tel: list = []
     n_micro = max(args.microbatches, 1)
     if args.telemetry:
@@ -163,9 +208,8 @@ def main():
         # live detection runs on the de-tuned wall-clock preset: CPU step
         # times jitter far more than the simulator's episodes
         wcfg = obs_detect.DetectorConfig.wallclock()
-        if args.comm == "mlsl":
-            tel_engine = tr.make_comm_engine(model, mesh, planner, comm)
-            monitor = obs_detect.HealthMonitor.from_plan(tel_engine.plan,
+        if engine is not None:
+            monitor = obs_detect.HealthMonitor.from_plan(engine.plan,
                                                          config=wcfg)
             t_model_tel = list(monitor.t_model)
         else:
@@ -173,26 +217,48 @@ def main():
             # messages: only the generic step_time_drift alarm is reachable
             monitor = obs_detect.HealthMonitor(config=wcfg)
 
-    with compat.set_mesh(mesh):
-        state = tr.make_train_state(model, optimizer,
-                                    jax.random.PRNGKey(args.seed))
-        step_fn = jax.jit(tr.make_train_step(model, optimizer, mesh, planner,
-                                             comm))
+    batch_sh = tr.batch_shardings(planner, model, args.batch)
+
+    def make_batch(raw):
+        kw = {}
+        if cfg.vlm_img_tokens:
+            kw["img_embeds"] = np.zeros(
+                (args.batch, cfg.vlm_img_tokens, cfg.vlm_d_vision),
+                np.float32)
+        if cfg.encoder is not None:
+            kw["frame_embeds"] = np.zeros(
+                (args.batch, cfg.encoder.n_frames, cfg.encoder.d_input),
+                np.float32)
+        return jax.device_put(Batch(tokens=raw["tokens"],
+                                    labels=raw["labels"], **kw), batch_sh)
+
+    dev = jax.devices()[0]
+    # the state is built in place in the layout the step keeps it in, and
+    # donated to the step: one copy of it on the devices, and one compile
+    state_sh = tr.state_shardings(planner, model, optimizer, engine)
+    with jax.set_mesh(mesh):
+        state = jax.jit(
+            lambda key: tr.make_train_state(model, optimizer, key,
+                                            engine=engine),
+            out_shardings=state_sh)(jax.random.PRNGKey(args.seed))
+        step_fn = jax.jit(
+            tr.make_train_step(model, optimizer, mesh, planner, comm,
+                               engine=engine),
+            out_shardings=(state_sh, NamedSharding(mesh, P())),
+            donate_argnums=0)
         print(f"arch={cfg.name} params={model.n_params():,} comm={args.comm}"
-              f"/{args.wire} mesh={dict(mesh.shape)}")
-        t0 = time.time()
-        for s, raw in enumerate(pipeline.iterate(dcfg, args.steps)):
-            kw = {}
-            if cfg.vlm_img_tokens:
-                kw["img_embeds"] = jnp.zeros(
-                    (args.batch, cfg.vlm_img_tokens, cfg.vlm_d_vision),
-                    jnp.float32)
-            if cfg.encoder is not None:
-                kw["frame_embeds"] = jnp.zeros(
-                    (args.batch, cfg.encoder.n_frames, cfg.encoder.d_input),
-                    jnp.float32)
-            batch = Batch(tokens=jnp.asarray(raw["tokens"]),
-                          labels=jnp.asarray(raw["labels"]), **kw)
+              f"/{args.wire} mesh={dict(mesh.shape)} devices={dev.platform}:"
+              f"{dev.device_kind}x{jax.device_count()}")
+        batches = (make_batch(raw)
+                   for raw in pipeline.iterate(dcfg, args.steps))
+        first = next(batches)
+        t0 = time.perf_counter()
+        compiled = step_fn.lower(state, first).compile()
+        compile_s = time.perf_counter() - t0
+        print(f"compiled step in {compile_s:.1f}s", flush=True)
+        history = []
+        t0 = time.perf_counter()
+        for s, batch in enumerate(itertools.chain([first], batches)):
             if meter is not None:
                 # metering blocks on each step's result (async dispatch would
                 # attribute step k's time to k+1); span per step when tracing
@@ -226,11 +292,11 @@ def main():
                                loss=meter.last_loss, exposed_frac=exposed)
                     fired = monitor.observe_step(s, meter.last_dt,
                                                  exposed_frac=exposed)
-                    if tel_engine is not None and telem.should_sample(s):
+                    if engine is not None and telem.should_sample(s):
                         # sampled standalone replay BETWEEN steps — the hot
                         # path never runs it; first sample pays the compile
                         if timer is None:
-                            timer = tel_engine.bucket_timer(mesh)
+                            timer = engine.bucket_timer(mesh)
                             sampled = timer.sample(warmup=1)
                         else:
                             sampled = timer.sample()
@@ -243,24 +309,24 @@ def main():
             else:
                 state, metrics = step_fn(state, batch)
             if s % args.log_every == 0 or s == args.steps - 1:
+                loss, gnorm = (float(metrics["loss"]),
+                               float(metrics["grad_norm"]))
+                t = time.perf_counter()
+                history.append((s, loss, gnorm, t))
                 if meter is not None:
-                    print(f"{meter.summary()} ({time.time() - t0:.1f}s)",
-                          flush=True)
+                    print(f"{meter.summary()} ({t - t0:.1f}s)", flush=True)
                 else:
-                    print(f"step {s:5d} loss {float(metrics['loss']):.4f} "
-                          f"gnorm {float(metrics['grad_norm']):.3f} "
-                          f"({time.time() - t0:.1f}s)", flush=True)
+                    print(f"step {s:5d} loss {loss:.4f} gnorm {gnorm:.3f} "
+                          f"({t - t0:.1f}s)", flush=True)
         if args.stats or tracer is not None:
             _emit_observability(args, mesh, planner, comm, model, meter,
-                                tracer, engine=tel_engine)
+                                tracer, engine=engine)
         if telem is not None:
             telem.close()
             print(f"telemetry: {telem.path} ({telem.n_records} records)")
             _report_health(monitor)
-    if args.ckpt_dir:
-        ckpt.save(args.ckpt_dir, {"params": state.params}, step=args.steps)
-        print(f"checkpoint -> {args.ckpt_dir}")
-    return 0
+    return RunResult(state=state, history=history, compiled=compiled,
+                     compile_s=compile_s, n_compiles=step_fn._cache_size())
 
 
 def _report_health(monitor) -> None:

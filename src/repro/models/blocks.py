@@ -92,9 +92,6 @@ class BlockCtx:
     batch_axes: tuple = ("data",)
     fsdp_axes: tuple = ()
     wgather_wire: str = "bf16"      # int8: quantized ZeRO weight gathers
-    # python-unroll the block scan: required inside partial-manual shard_map
-    # regions on JAX 0.4.x (compat.PARTIAL_MANUAL_SCAN_OK)
-    unroll: bool = False
     # hybrid execution: activation-exchange axis for tensor-parallel blocks.
     # Whether a given block actually runs sharded is detected from its shard
     # shapes (attn_tp / mlp_tp) — the per-layer hybrid plan leaves fallback

@@ -29,7 +29,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import MoEConfig
 from repro.core import planner as pl
 from repro.models import common, mlp
@@ -188,7 +187,7 @@ def moe_apply_ep(p: dict, x: jax.Array, m: MoEConfig, *, act: str,
             # axes; gather just-in-time before use (int8 wire optional).
             for a in reversed(fsdp_axes):
                 if wgather_wire == "int8":
-                    psz = compat.axis_size(a)
+                    psz = jax.lax.axis_size(a)
                     w1 = _quantized_gather(w1, a, 1, psz)
                     w3 = _quantized_gather(w3, a, 1, psz)
                     w2 = _quantized_gather(w2, a, 2, psz)
@@ -238,7 +237,7 @@ def moe_apply_ep(p: dict, x: jax.Array, m: MoEConfig, *, act: str,
     wspec_out = P(model_axis, None,
                   fsdp_axes if len(fsdp_axes) > 1 else
                   (fsdp_axes[0] if fsdp_axes else None))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None), wspec_in,
                   wspec_out, wspec_in),

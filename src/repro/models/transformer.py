@@ -21,7 +21,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core import planner as pl
 from repro.models import blocks, common
@@ -114,15 +113,13 @@ class Model:
     def _ctx(self, enc_out=None, window_override=None, moe_impl="gather",
              kv_chunk=None, kv_dtype="native", mesh=None,
              batch_axes=("data",), fsdp_axes=(),
-             wgather_wire="bf16", unroll=False,
-             tp_axis=None) -> blocks.BlockCtx:
+             wgather_wire="bf16", tp_axis=None) -> blocks.BlockCtx:
         return blocks.BlockCtx(cfg=self.cfg, window_override=window_override,
                                enc_out=enc_out, moe_impl=moe_impl,
                                kv_chunk=kv_chunk, kv_dtype=kv_dtype,
                                mesh=mesh, batch_axes=batch_axes,
                                fsdp_axes=fsdp_axes,
-                               wgather_wire=wgather_wire, unroll=unroll,
-                               tp_axis=tp_axis)
+                               wgather_wire=wgather_wire, tp_axis=tp_axis)
 
     def _embed(self, params: dict, batch: Batch, *, pos0: int = 0) -> jax.Array:
         cfg = self.cfg
@@ -138,21 +135,20 @@ class Model:
                                                  axis=0)[None]
         return h
 
-    def _encode(self, params: dict, frame_embeds: jax.Array, *,
-                unroll: bool = False) -> jax.Array:
+    def _encode(self, params: dict, frame_embeds: jax.Array) -> jax.Array:
         cfg = self.cfg
         p = params["encoder"]
         h = frame_embeds.astype(cfg.dtype)
         if "in_proj" in p:
             h = h @ p["in_proj"]
         h = h + p["pos"][None]
-        ctx = self._ctx(unroll=unroll)
+        ctx = self._ctx()
 
         def body(carry, pslice):
             hh, _ = blocks.block_apply("enc", pslice, carry, ctx)
             return hh, None
 
-        h, _ = compat.maybe_scan(body, h, p["blocks"], unroll=unroll)
+        h, _ = jax.lax.scan(body, h, p["blocks"])
         return blocks.norm_apply(p["ln_f"], h, cfg)
 
     def _run_blocks(self, params: dict, h: jax.Array, ctx: blocks.BlockCtx):
@@ -173,10 +169,7 @@ class Model:
 
             if cfg.remat:
                 body = jax.checkpoint(body)
-            # unroll: partial-manual shard_map regions on JAX 0.4.x cannot
-            # hold a scan loop (compat.PARTIAL_MANUAL_SCAN_OK)
-            (h, aux0), _ = compat.maybe_scan(body, (h, aux0), stacked,
-                                             unroll=ctx.unroll)
+            (h, aux0), _ = jax.lax.scan(body, (h, aux0), stacked)
 
         for i, kind in enumerate(cfg.tail_layers):
             h, a = blocks.block_apply(kind, params["tail"][f"t{i}_{kind}"], h,
@@ -200,8 +193,7 @@ class Model:
         """Full-sequence logits (training / evaluation)."""
         enc_out = None
         if self.cfg.encoder is not None:
-            enc_out = self._encode(params, batch.frame_embeds,
-                                   unroll=ctx_kw.get("unroll", False))
+            enc_out = self._encode(params, batch.frame_embeds)
         ctx = self._ctx(enc_out=enc_out, **ctx_kw)
         h = self._embed(params, batch)
         h, self._last_aux = self._run_blocks(params, h, ctx)

@@ -359,8 +359,6 @@ class BucketTimer:
         import numpy as np
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
-
         p = engine.plan
         rng = np.random.default_rng(seed)
         bspec = p.data_axes if len(p.data_axes) > 1 else p.data_axes[0]
@@ -376,14 +374,14 @@ class BucketTimer:
                 flat = jnp.asarray(
                     rng.standard_normal(bucket.n_elems), jnp.float32)
                 if engine.ef_applied(bi):
-                    fn = compat.shard_map(
+                    fn = jax.shard_map(
                         lambda f, r, _bi=bi:
                             engine._reduce_bucket(f, r, _bi)[0],
                         mesh=mesh, in_specs=(P(), P(bspec)), out_specs=P(),
                         axis_names=manual, check_vma=False)
                     args = (flat, residuals[bi])
                 else:
-                    fn = compat.shard_map(
+                    fn = jax.shard_map(
                         lambda f, _bi=bi:
                             engine._reduce_bucket(f, None, _bi)[0],
                         mesh=mesh, in_specs=(P(),), out_specs=P(),
@@ -401,7 +399,7 @@ class BucketTimer:
                         cl.allreduce(v, _axes, wire=_wire, mean=True)
                         for v in vs)
 
-                fn = compat.shard_map(
+                fn = jax.shard_map(
                     leafwise, mesh=mesh,
                     in_specs=tuple(P() for _ in vals),
                     out_specs=tuple(P() for _ in vals),

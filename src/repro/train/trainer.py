@@ -34,9 +34,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import scheduler
 from repro.core.engine import CommConfig, CommEngine
 from repro.core.planner import Planner
@@ -44,7 +43,7 @@ from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib
 
 __all__ = ["CommConfig", "TrainState", "make_train_state", "make_comm_engine",
-           "make_train_step", "state_shardings"]
+           "make_train_step", "state_shardings", "batch_shardings"]
 
 
 @dataclasses.dataclass
@@ -61,29 +60,47 @@ jax.tree_util.register_dataclass(
 
 
 def make_train_state(model: Model, optimizer: opt_lib.Optimizer,
-                     key: jax.Array) -> TrainState:
+                     key: jax.Array, *,
+                     engine: CommEngine | None = None) -> TrainState:
+    """Fresh state. Pass the step's `engine` when it applies error feedback:
+    the residuals then exist before the first step, so the state's tree
+    structure never changes and the step compiles once."""
     params = model.init(key)
     return TrainState(params=params, opt_state=optimizer.init(params),
-                      step=jnp.zeros((), jnp.int32))
+                      step=jnp.zeros((), jnp.int32),
+                      comm_residuals=(None if engine is None
+                                      else engine.init_residuals()))
 
 
 def _layer_index_fn():
     return scheduler.default_layer_index
 
 
-def _batch_specs(planner: Planner, model: Model, batch_size: int) -> Batch:
+def _named(mesh: Mesh, specs):
+    return jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
+                                  is_leaf=lambda x: isinstance(x, P))
+
+
+def batch_shardings(planner: Planner, model: Model,
+                    batch_size: int) -> Batch:
+    """Where a global batch goes: split over the batch axes."""
     cfg = model.cfg
     tok = planner.tokens_spec(batch_size, extra_dims=1)
     three = planner.tokens_spec(batch_size, extra_dims=2)
-    return Batch(
+    return _named(planner.mesh, Batch(
         tokens=tok, labels=tok, mask=None,
         img_embeds=three if cfg.vlm_img_tokens else None,
-        frame_embeds=three if cfg.encoder is not None else None)
+        frame_embeds=three if cfg.encoder is not None else None))
 
 
 def state_shardings(planner: Planner, model: Model,
-                    optimizer: opt_lib.Optimizer) -> TrainState:
-    """PartitionSpec tree for TrainState (opt state mirrors params)."""
+                    optimizer: opt_lib.Optimizer,
+                    engine: CommEngine | None = None) -> TrainState:
+    """Where the train step keeps its state: parameters as the planner lays
+    them out (the optimizer state mirrors them), the error-feedback
+    residuals (with `engine`) split over the batch axes. Building the
+    state in this layout keeps every device busy from the start, and the
+    step's outputs come back in it, so the step compiles once."""
     defs = model.param_defs()
     pspecs = planner.tree_specs(defs, stacked_paths=Model.stacked_path)
     params_shape = jax.eval_shape(lambda: jax.tree_util.tree_map(
@@ -91,8 +108,14 @@ def state_shardings(planner: Planner, model: Model,
     opt_shape = jax.eval_shape(optimizer.init, params_shape)
     # all in-tree optimizers keep {name: params-shaped tree} states
     opt_specs = {k: pspecs for k in opt_shape}
-    return TrainState(params=pspecs, opt_state=opt_specs,
-                      step=P(), comm_residuals=None)
+    res_specs = None
+    if engine is not None:
+        axes = planner.batch_axes
+        res_specs = engine.residual_specs(P(axes if len(axes) > 1
+                                            else axes[0]))
+    return _named(planner.mesh, TrainState(
+        params=pspecs, opt_state=opt_specs, step=P(),
+        comm_residuals=res_specs))
 
 
 def _grad_struct(model: Model):
@@ -156,8 +179,13 @@ def make_comm_engine(model: Model, mesh: Mesh, planner: Planner,
 
 def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
                     planner: Planner, comm: CommConfig,
-                    *, grad_clip: float = 1.0):
-    """Returns train_step(state, batch) -> (state, metrics)."""
+                    *, grad_clip: float = 1.0,
+                    engine: CommEngine | None = None):
+    """Returns train_step(state, batch) -> (state, metrics).
+
+    mlsl mode reduces through `engine` (built here when not given); with
+    error feedback the state must carry its residuals from the start
+    (make_train_state(..., engine=engine))."""
     cfg = model.cfg
     data_axes = planner.batch_axes
     fsdp_axes = planner.batch_axes if planner.fsdp else ()
@@ -180,16 +208,12 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
                          "manual data path")
 
     # mlsl mode runs the step in a shard_map manual over the batch axes (plus
-    # the tp axis under hybrid); if any OTHER mesh axis is >1 the region is
-    # PARTIAL-manual, which on JAX 0.4.x cannot contain scan loops
-    # (compat.PARTIAL_MANUAL_SCAN_OK) -- unroll the block/accum scans there
-    # (pattern_repeats is small for the smoke configs this CPU path runs;
-    # mesh-scale dry-runs use gspmd).
+    # the tp axis under hybrid). Other axes of size 1 go manual too: that
+    # changes no sharding, and Mosaic kernels (the int8 wire) cannot be
+    # auto-partitioned, so they need a region with no auto axis.
     manual_axes = tuple(data_axes) + ((tp_axis,) if tp_axis else ())
-    partial_manual = any(mesh.shape[a] > 1 for a in mesh.axis_names
-                         if a not in manual_axes)
-    unroll_scans = (comm.mode == "mlsl" and partial_manual
-                    and not compat.PARTIAL_MANUAL_SCAN_OK)
+    manual_axes += tuple(a for a in mesh.axis_names
+                         if a not in manual_axes and mesh.shape[a] == 1)
 
     loss_kw = dict(moe_impl=comm.moe_impl, mesh=mesh,
                    batch_axes=data_axes, fsdp_axes=fsdp_axes,
@@ -197,8 +221,6 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         if comm.moe_impl == "ep" else {}
     if comm.kv_chunk:
         loss_kw["kv_chunk"] = comm.kv_chunk
-    if unroll_scans:
-        loss_kw["unroll"] = True
     if tp_axis is not None:
         # blocks detect model-sharded weights by their shard shapes and
         # place the f/g activation collectives; DP-fallback layers see
@@ -233,8 +255,7 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
                 lambda a, b: a + b.astype(jnp.float32), gsum, g)
             return (gsum, lsum + loss), None
 
-        (gsum, lsum), _ = compat.maybe_scan(body, (gz, jnp.zeros(())), micro,
-                                            unroll=unroll_scans)
+        (gsum, lsum), _ = jax.lax.scan(body, (gz, jnp.zeros(())), micro)
         grads = jax.tree_util.tree_map(
             lambda g, pp: (g / acc).astype(pp.dtype), gsum, params)
         return lsum / acc, grads
@@ -269,7 +290,8 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
     # The engine owns the whole bucket-reduction data path: planning,
     # flat-vs-two-level routing, wire precision, error feedback, priority
     # chain.
-    engine = make_comm_engine(model, mesh, planner, comm)
+    if engine is None:
+        engine = make_comm_engine(model, mesh, planner, comm)
 
     if tp_axis is None:
         pspecs = None
@@ -367,9 +389,8 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
                 bacc, res, token = exchange(g, bacc, res, token)
                 return (bacc, lsum + loss, res, token), None
 
-            (bacc, lsum, residuals, _), _ = compat.maybe_scan(
-                body, (bacc, loss0, residuals, token), rest,
-                unroll=unroll_scans)
+            (bacc, lsum, residuals, _), _ = jax.lax.scan(
+                body, (bacc, loss0, residuals, token), rest)
         else:
             # software pipeline: iteration k reduces microbatch k-1's
             # buckets beside microbatch k's compute (the reduction chain is
@@ -384,9 +405,9 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
                         pending, bacc, res, token)
                 return (bacc, lsum + loss, _to_f32(g), res, token), None
 
-            (bacc, lsum, pending, residuals, token), _ = compat.maybe_scan(
+            (bacc, lsum, pending, residuals, token), _ = jax.lax.scan(
                 body, (engine.init_accum(), loss0, _to_f32(g0), residuals,
-                       token0), rest, unroll=unroll_scans)
+                       token0), rest)
             with jax.named_scope("microbatch/exchange"):
                 bacc, residuals, _ = engine.reduce_accum_chained(
                     pending, bacc, residuals, token)
@@ -407,6 +428,13 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         else:
             loss, grads = grads_fn(params, batch)
             grads, residuals = engine.reduce(grads, residuals)
+            # the engine reduces in f32; hand the optimizer the parameters'
+            # dtype, as the gspmd step does. The barrier keeps that cast:
+            # XLA's excess-precision rewrite would otherwise hold the f32
+            # messages as the tree the global norm waits on (3.5 GB at
+            # Yi-6B widths on one chip)
+            grads = jax.lax.optimization_barrier(jax.tree_util.tree_map(
+                lambda g, pp: g.astype(pp.dtype), grads, params))
         grads, gnorm = clip_grads(grads, grad_clip)
         loss = jax.lax.pmean(loss, data_axes)
         params, opt_state = optimizer.update(grads, opt_state, params, step)
@@ -436,9 +464,11 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
             opt_specs = {k: params_specs for k in state.opt_state}
         residuals = state.comm_residuals
         if engine.plan.use_ef and residuals is None:
-            residuals = engine.init_residuals()
+            raise ValueError(
+                "error feedback needs its residuals in the state before the "
+                "first step: make_train_state(..., engine=engine)")
 
-        out = compat.shard_map(
+        out = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(params_specs, opt_specs, replicated, res_spec,
                       batch_in_specs),

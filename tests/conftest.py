@@ -20,7 +20,7 @@ if "--xla_force_host_platform_device_count" not in \
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch import mesh as mesh_lib  # noqa: E402
 
 
 def pytest_configure(config):
@@ -30,19 +30,17 @@ def pytest_configure(config):
 
 @pytest.fixture(scope="session")
 def mesh11():
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    return mesh_lib.make_host_mesh()
 
 
 @pytest.fixture(scope="session")
 def mesh8():
     """("node"=2, "local"=4) factored data-parallel mesh over the 8 virtual
     devices -- the hierarchical-collectives test mesh."""
-    from repro.launch import mesh as mesh_lib
     return mesh_lib.make_hier_mesh(node=2, local=4)
 
 
 @pytest.fixture(scope="session")
 def abstract_pod():
-    return compat.abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 

@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.configs import registry
 from repro.configs.shapes import SHAPES
 from repro.core.planner import Planner
+from repro.launch import mesh as mesh_lib
 from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib
 from repro.train import trainer as tr
@@ -17,8 +17,7 @@ from repro.train import trainer as tr
 
 @pytest.fixture(scope="module")
 def mesh():
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    return mesh_lib.make_host_mesh()
 
 
 def _batch(cfg, key, B, S, with_labels=True):
@@ -42,7 +41,7 @@ def test_smoke_train_step(arch, mesh):
     model = Model(cfg)
     opt = opt_lib.adamw(1e-3)
     planner = Planner(mesh=mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
         step = jax.jit(tr.make_train_step(model, opt, mesh, planner,
                                           tr.CommConfig()))

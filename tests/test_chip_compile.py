@@ -1,0 +1,102 @@
+"""Compile-only checks against a described TPU v5e (no chip attached).
+
+The TPU compiler refuses what interpret mode accepts: misaligned block
+shapes, too much VMEM. These tests lower the int8-wire kernels at a real
+bucket size for v5e and assert the compiled program carries the Mosaic
+kernel (``tpu_custom_call``). The topology is described inside a fixture,
+never while a module is imported: only one process may load the TPU
+library, and the suite runs under several workers.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import quant8
+
+# one 25 MB f32 gradient bucket (the engine's default bucket_bytes) in
+# (n_blocks, block) layout
+N_BLOCKS, BLOCK = 12_800, quant8.DEFAULT_BLOCK
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                    # noqa: BLE001 - any failure
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _arg(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args, **static):
+    return fn.lower(*args, **static).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_quantize_blocks_compiles_for_v5e(one_chip, dtype):
+    x = _arg((N_BLOCKS, BLOCK), dtype, one_chip)
+    assert "tpu_custom_call" in _compiled_text(quant8.quantize_blocks, x)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_quantize_ef_blocks_compiles_for_v5e(one_chip, dtype):
+    x = _arg((N_BLOCKS, BLOCK), dtype, one_chip)
+    res = _arg((N_BLOCKS, BLOCK), jnp.float32, one_chip)
+    assert "tpu_custom_call" in _compiled_text(quant8.quantize_ef_blocks,
+                                               x, res)
+
+
+@pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
+def test_dequantize_blocks_compiles_for_v5e(one_chip, out_dtype):
+    q = _arg((N_BLOCKS, BLOCK), jnp.int8, one_chip)
+    s = _arg((N_BLOCKS,), jnp.float32, one_chip)
+    assert "tpu_custom_call" in _compiled_text(quant8.dequantize_blocks, q,
+                                               s, out_dtype=out_dtype)
+
+
+@pytest.mark.parametrize("acc_dtype", [jnp.float32, jnp.bfloat16])
+def test_dequantize_accumulate_blocks_compiles_for_v5e(one_chip, acc_dtype):
+    q = _arg((N_BLOCKS, BLOCK), jnp.int8, one_chip)
+    s = _arg((N_BLOCKS,), jnp.float32, one_chip)
+    acc = _arg((N_BLOCKS, BLOCK), acc_dtype, one_chip)
+    assert "tpu_custom_call" in _compiled_text(
+        quant8.dequantize_accumulate_blocks, q, s, acc)
+
+
+def test_engine_on_v5e_mesh_plans_compiled_kernels(topo, monkeypatch):
+    """The engine resolves the wire backend from its mesh's devices, not
+    from the host's default (CPU) backend: a step built for the chip
+    carries the compiled kernels, never the oracle or the interpreter."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.core import engine as eng
+    monkeypatch.setenv("REPRO_QUANT_BACKEND", "jnp")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("node", "local"),
+                axis_types=(AxisType.Auto,) * 2)
+    comm = eng.CommConfig(mode="mlsl", wire="int8", error_feedback=True,
+                          hier=True)
+    grads = {"w": jax.ShapeDtypeStruct((N_BLOCKS, BLOCK), jnp.float32)}
+    engine = eng.CommEngine.create(grads, comm, mesh, ("node", "local"))
+    assert engine.plan.quant_backend == "pallas"
+    assert engine.plan.hier_spec.backend == "pallas"

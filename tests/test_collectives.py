@@ -7,16 +7,15 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import collectives as cl
 
 
 def _run1(fn, x, mesh11):
     # jit-wrapped, as in the trainer: inside jit the partial-manual shard_map
     # accepts replicated specs with check_vma=False.
-    return jax.jit(compat.shard_map(fn, mesh=mesh11, in_specs=P(),
-                                    out_specs=P(), axis_names={"data"},
-                                    check_vma=False))(x)
+    return jax.jit(jax.shard_map(fn, mesh=mesh11, in_specs=P(),
+                                 out_specs=P(), axis_names={"data"},
+                                 check_vma=False))(x)
 
 
 @pytest.mark.parametrize("wire", cl.WIRES)
@@ -37,10 +36,10 @@ def test_allreduce_ef_residual_tracks_error(mesh11):
     def f(u, r):
         return cl.allreduce_ef(u, r, ("data",))
 
-    y, res = jax.jit(compat.shard_map(f, mesh=mesh11, in_specs=(P(), P()),
-                                      out_specs=(P(), P()),
-                                      axis_names={"data"},
-                                      check_vma=False))(x, res0)
+    y, res = jax.jit(jax.shard_map(f, mesh=mesh11, in_specs=(P(), P()),
+                                   out_specs=(P(), P()),
+                                   axis_names={"data"},
+                                   check_vma=False))(x, res0)
     # y + residual == bf16(x): the residual holds exactly the quantization
     # error of the bf16-wire reduce-scatter shard
     xb = np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32))

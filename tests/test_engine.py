@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import registry
 from repro.core import engine as eng
 from repro.core import hier, hw, planner, scheduler, simulator as sim
@@ -106,10 +105,11 @@ def _reduce8(mesh8, comm, tree, **plan_kw):
         out, _ = engine.reduce(local, None)
         return out
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         f, mesh=mesh8,
         in_specs=(jax.tree_util.tree_map(lambda _: DSPEC, tree),),
-        out_specs=jax.tree_util.tree_map(lambda _: P(), tree)))(tree)
+        out_specs=jax.tree_util.tree_map(lambda _: P(), tree),
+        check_vma=False))(tree)
 
 
 @pytest.fixture(scope="module")
@@ -139,7 +139,7 @@ def test_engine_reduce_per_leaf_when_not_fusable(mesh8, stacked_tree):
 
 
 def test_engine_skip_reduce_is_identity():
-    m = compat.make_mesh((1, 1), ("node", "local"))
+    m = jax.make_mesh((1, 1), ("node", "local"))
     t = _tree()
     engine = eng.CommEngine.create(t, eng.CommConfig(mode="mlsl",
                                                      skip_reduce=True),
@@ -172,11 +172,13 @@ def test_engine_ef_residual_state(mesh8):
     assert len(res) == engine.plan.n_buckets
     specs = engine.residual_specs(P(DATA_AXES))
     assert len(specs) == engine.plan.n_buckets
-    # flat-routed bucket residuals: dp * per-rank fabric shard
+    # flat-routed bucket residuals: dp * per-rank fabric shard, held in the
+    # wire kernels' (blocks, QUANT_BLOCK) layout
     from repro.core import collectives as cl
     for bi, (r, b) in enumerate(zip(res, engine.plan.buckets.buckets)):
         assert engine.ef_applied(bi)
-        assert r.shape == (cl.ef_residual_shape(b.n_elems, 8)[0] * 8,)
+        n = cl.ef_residual_shape(b.n_elems, 8)[0]
+        assert r.shape == (n // cl.QUANT_BLOCK * 8, cl.QUANT_BLOCK)
 
 
 def test_engine_ef_residuals_only_where_applied(mesh8):
@@ -195,7 +197,7 @@ def test_engine_ef_residuals_only_where_applied(mesh8):
     # the data path carries the placeholders through unchanged
     tree = _tree()
     tspec = jax.tree_util.tree_map(lambda _: P(), tree)
-    out, new_res = jax.jit(compat.shard_map(
+    out, new_res = jax.jit(jax.shard_map(
         lambda t, r: engine.reduce(t, r), mesh=mesh8,
         in_specs=(tspec, res_spec), out_specs=(tspec, res_spec),
         axis_names=set(DATA_AXES), check_vma=False))(tree, res)
@@ -236,7 +238,7 @@ def _train(mesh8, comm, steps=2, seed=0):
     pln = Planner(mesh=mesh8)
     dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=32, global_batch=16,
                                seed=seed)
-    with compat.set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(seed))
         step = jax.jit(tr.make_train_step(model, opt, mesh8, pln, comm))
         losses = []

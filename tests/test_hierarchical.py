@@ -1,5 +1,5 @@
 """Hierarchical two-level collectives on the 8-virtual-device harness, plus
-the compat shim they sit on.
+the JAX sharding behaviour they sit on.
 
 The mesh8 fixture (conftest) factors the 8 fake host devices into
 ("node"=2, "local"=4): "local" stands for the fast intra-node link, "node"
@@ -23,7 +23,6 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import collectives as cl
 from repro.core import hier, hw, planner, scheduler, simulator as sim
 
@@ -34,8 +33,8 @@ def _run8(fn, mesh8, *args, in_specs=None, out_specs=P()):
     """Run fn manually over both data axes of the (2, 4) mesh."""
     if in_specs is None:
         in_specs = tuple(DSPEC for _ in args)
-    return jax.jit(compat.shard_map(fn, mesh=mesh8, in_specs=in_specs,
-                                    out_specs=out_specs))(*args)
+    return jax.jit(jax.shard_map(fn, mesh=mesh8, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))(*args)
 
 
 @pytest.fixture(scope="module")
@@ -104,9 +103,9 @@ def test_hier_error_feedback_roundtrip(mesh8, x8):
     def f(u, r):
         return hier.hier_allreduce_ef(u[0], r, spec)
 
-    y, res = jax.jit(compat.shard_map(
+    y, res = jax.jit(jax.shard_map(
         f, mesh=mesh8, in_specs=(DSPEC, DSPEC),
-        out_specs=(P(), DSPEC)))(x8, res0)
+        out_specs=(P(), DSPEC), check_vma=False))(x8, res0)
     ref = _psum_ref(mesh8, x8)
     err = np.max(np.abs(np.asarray(y) - ref)) / np.max(np.abs(ref))
     assert err < 2e-2, err
@@ -241,7 +240,7 @@ def test_trainer_hier_matches_flat_mlsl(mesh8):
     results = {}
     for name, comm in (("flat", tr.CommConfig(mode="mlsl")),
                        ("hier", tr.CommConfig(mode="mlsl", hier=True))):
-        with compat.set_mesh(mesh8):
+        with jax.set_mesh(mesh8):
             state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
             step = jax.jit(tr.make_train_step(model, opt, mesh8, pln, comm))
             for raw in pipeline.iterate(dcfg, 3):
@@ -273,7 +272,7 @@ def test_trainer_topo_routing_trains(mesh8):
     opt = opt_lib.adamw(3e-3)
     comm = tr.CommConfig(mode="mlsl", hier=True, topo="xeon-shm-10gbe")
     dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=32, global_batch=8)
-    with compat.set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
         step = jax.jit(tr.make_train_step(model, opt, mesh8,
                                           Planner(mesh=mesh8), comm))
@@ -311,64 +310,55 @@ def test_trainer_hier_requires_factored_mesh(mesh11):
 
 
 # --------------------------------------------------------------------------
-# compat shim unit tests (both API spellings of the call sites)
+# the JAX sharding behaviour the data path relies on
 # --------------------------------------------------------------------------
 
-def test_compat_make_mesh_accepts_both_spellings():
-    m1 = compat.make_mesh((1, 1), ("a", "b"))
-    m2 = compat.make_mesh((1, 1), ("a", "b"),
-                          axis_types=(compat.AxisType.Auto,) * 2)
-    assert m1.axis_names == m2.axis_names == ("a", "b")
-    assert dict(m1.shape) == dict(m2.shape) == {"a": 1, "b": 1}
+def test_mesh_helpers_make_auto_axes():
+    """jax.make_mesh defaults to Explicit axes; the launch helpers build
+    every mesh Auto, which the GSPMD baseline and the partial-manual
+    regions below need."""
+    from jax.sharding import AxisType
+
+    from repro.launch import mesh as mesh_lib
+    for m, shape in ((mesh_lib.make_host_mesh(), {"data": 1, "model": 1}),
+                     (mesh_lib.make_hier_mesh(2, 4),
+                      {"node": 2, "local": 4}),
+                     (mesh_lib.make_hier_mesh(2, 2, 2),
+                      {"node": 2, "local": 2, "model": 2})):
+        assert dict(m.shape) == shape
+        assert m.axis_types == (AxisType.Auto,) * len(shape)
 
 
-def test_compat_abstract_mesh_shape_and_names():
-    am = compat.abstract_mesh((16, 16), ("data", "model"))
-    assert dict(am.shape) == {"data": 16, "model": 16}
-    assert tuple(am.axis_names) == ("data", "model")
+def test_abstract_mesh_shape_and_names(abstract_pod):
+    assert dict(abstract_pod.shape) == {"data": 16, "model": 16}
+    assert tuple(abstract_pod.axis_names) == ("data", "model")
 
 
-def test_compat_axis_type_members():
-    # call sites only ever pass .Auto today; all three members must exist
-    for member in ("Auto", "Explicit", "Manual"):
-        assert hasattr(compat.AxisType, member)
-
-
-def test_compat_shard_map_fully_manual_default(mesh8):
+def test_shard_map_fully_manual_default(mesh8):
     x = jnp.arange(8.0)
-    y = jax.jit(compat.shard_map(
+    y = jax.jit(jax.shard_map(
         lambda u: lax.psum(u, (hier.NODE_AXIS, hier.LOCAL_AXIS)),
-        mesh=mesh8, in_specs=DSPEC, out_specs=P()))(x)
+        mesh=mesh8, in_specs=DSPEC, out_specs=P(), check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(y), [28.0])
 
 
-def test_compat_shard_map_partial_manual_auto_complement():
-    """axis_names translates to the legacy `auto` complement set: the model
-    axis stays GSPMD while node/local are manual."""
-    mesh = compat.make_mesh((2, 2, 2), ("node", "local", "model"))
+def test_shard_map_partial_manual_keeps_model_axis_auto():
+    """axis_names is the manual set: the model axis stays GSPMD while
+    node/local are manual (the trainer's mlsl region under a model axis)."""
+    from repro.launch import mesh as mesh_lib
+    mesh = mesh_lib.make_hier_mesh(2, 2, 2)
     x = jnp.arange(8.0)
-    y = jax.jit(compat.shard_map(
+    y = jax.jit(jax.shard_map(
         lambda u: lax.psum(u, ("node", "local")),
         mesh=mesh, in_specs=P(("node", "local")), out_specs=P(),
         axis_names={"node", "local"}, check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(y), [12.0, 16.0])
 
 
-def test_compat_axis_size_in_manual_region(mesh8):
-    sizes = jax.jit(compat.shard_map(
-        lambda: (jnp.asarray(compat.axis_size(hier.NODE_AXIS), jnp.int32),
-                 jnp.asarray(compat.axis_size((hier.NODE_AXIS,
-                                               hier.LOCAL_AXIS)), jnp.int32)),
-        mesh=mesh8, in_specs=(), out_specs=(P(), P())))()
+def test_axis_size_in_manual_region(mesh8):
+    sizes = jax.jit(jax.shard_map(
+        lambda: (jnp.asarray(cl.axis_size(hier.NODE_AXIS), jnp.int32),
+                 jnp.asarray(cl.axis_size((hier.NODE_AXIS,
+                                           hier.LOCAL_AXIS)), jnp.int32)),
+        mesh=mesh8, in_specs=(), out_specs=(P(), P()), check_vma=False))()
     assert int(sizes[0]) == 2 and int(sizes[1]) == 8
-
-
-def test_compat_set_mesh_is_context_manager(mesh11):
-    with compat.set_mesh(mesh11):
-        pass
-
-
-def test_compat_version_parsing():
-    assert compat._parse_version("0.4.37") == (0, 4, 37)
-    assert compat._parse_version("0.5.0.dev20250101") == (0, 5, 0)
-    assert compat.JAX_VERSION >= compat.MIN_SUPPORTED

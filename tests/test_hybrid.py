@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import registry
 from repro.core import c2c, collectives as cl, hw, planner as pl
 from repro.data import pipeline
@@ -52,11 +51,11 @@ def test_fg_ops_match_dense_reference(mesh8):
         return jax.value_and_grad(loss_fn, argnums=(0, 1, 2))(w1, w2, x)
 
     w_specs = (P(None, "local"), P("local", None), P())
-    sharded = compat.shard_map(inner, mesh=mesh8, in_specs=w_specs,
-                               out_specs=(P(), w_specs), axis_names=AXES,
-                               check_vma=False)
+    sharded = jax.shard_map(inner, mesh=mesh8, in_specs=w_specs,
+                            out_specs=(P(), w_specs), axis_names=AXES,
+                            check_vma=False)
 
-    with compat.set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         loss, (g1, g2, gx) = sharded(w1, w2, x)
     ref = dense_loss(w1, w2, x)
     d1, d2, dx = jax.grad(dense_loss, argnums=(0, 1, 2))(w1, w2, x)
@@ -76,11 +75,11 @@ def test_tp_psum_scatter_matches_tp_psum(mesh8):
             # make per-rank values distinct so the reduction is exercised
             r = jax.lax.axis_index("local").astype(jnp.float32)
             return op(v * (1.0 + r), "local")
-        return compat.shard_map(inner, mesh=mesh8, in_specs=P(),
-                                out_specs=P(), axis_names=AXES,
-                                check_vma=False)(x)
+        return jax.shard_map(inner, mesh=mesh8, in_specs=P(),
+                             out_specs=P(), axis_names=AXES,
+                             check_vma=False)(x)
 
-    with compat.set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         a = run(cl.tp_psum)
         b = run(cl.tp_psum_scatter)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
@@ -91,7 +90,7 @@ def test_tp_psum_scatter_matches_tp_psum(mesh8):
 # ---------------------------------------------------------------------------
 
 def _amesh():
-    return compat.abstract_mesh((2, 4), ("node", "local"))
+    return jax.sharding.AbstractMesh((2, 4), ("node", "local"))
 
 
 def test_plan_hybrid_verdicts_match_execution():
@@ -219,7 +218,7 @@ def _train(mesh, cfg, planner, steps=2, seq=16, batch=8):
     comm = tr.CommConfig(mode="mlsl", hier=True)
     dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=seq,
                                global_batch=batch, seed=3)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
         step = jax.jit(tr.make_train_step(model, opt, mesh, planner, comm))
         metrics = []

@@ -11,7 +11,6 @@ import pytest
 
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.kernels import ops, quant8, ref
 
 SHAPES = [(8, 512), (16, 128), (64, 640), (8, 1024)]
@@ -56,6 +55,7 @@ def test_dequant_accumulate_matches_ref(shape):
 @pytest.mark.parametrize("n", [1, 100, 511, 512, 4097, 70000])
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_ops_roundtrip_arbitrary_sizes(n, backend):
+    backend = ops.wire_backend(backend)
     x = jax.random.normal(jax.random.PRNGKey(n), (n,)) * 0.01
     q, s, meta = ops.quantize(x, backend=backend)
     xr = ops.dequantize(q, s, meta, backend=backend)
@@ -66,7 +66,7 @@ def test_ops_roundtrip_arbitrary_sizes(n, backend):
 
 
 def test_roundtrip_zeros_and_extremes():
-    for backend in ("jnp", "pallas"):
+    for backend in (ops.wire_backend("jnp"), ops.wire_backend("pallas")):
         z = jnp.zeros((1000,))
         q, s, meta = ops.quantize(z, backend=backend)
         assert float(jnp.max(jnp.abs(ops.dequantize(q, s, meta,
@@ -131,6 +131,7 @@ def test_quantize_ef_blocks_pallas_vs_composed(shape, dtype):
 def test_quantize_cast_blocks_folds_wire_cast(backend):
     """quantize(bf16 buffer) == quantize(f32 copy of it): the wire cast is
     inside the tile/oracle, so no separate cast pass is ever needed."""
+    backend = ops.wire_backend(backend)
     x16 = (jax.random.normal(jax.random.PRNGKey(11), (3000,)) * 2
            ).astype(jnp.bfloat16)
     q_a, s_a, _ = ops.quantize(x16, backend=backend)
@@ -145,6 +146,7 @@ def test_ops_quantize_ef_odd_sizes(n, backend):
     """Shape-polymorphic fused EF: padding round-trips and the residual
     comes back in the caller's (odd) shape with the invariant
     y = dequant(q) + new_residual holding per element."""
+    backend = ops.wire_backend(backend)
     x = jax.random.normal(jax.random.PRNGKey(n), (n,)).astype(jnp.bfloat16)
     res = jax.random.normal(jax.random.PRNGKey(n + 1), (n,)) * 0.01
     q, s, meta, new_res = ops.quantize_ef(x, res, backend=backend)
@@ -238,9 +240,20 @@ def test_backend_policy_is_single_sourced():
     assert 'backend="pallas"' not in src and "backend='pallas'" not in src
     with pytest.raises(ValueError, match="unknown quantization backend"):
         ops.wire_backend("cuda")
-    assert ops.wire_backend("pallas") == "pallas"
-    assert ops.wire_backend("jnp") == "jnp"
-    assert ops.wire_backend() in ("pallas", "jnp")
+    assert ops.wire_backend("pallas", "cpu") == "interpret"
+    assert ops.wire_backend("jnp", "cpu") == "jnp"
+    assert ops.wire_backend(platform="cpu") in ("interpret", "jnp")
+
+
+@pytest.mark.parametrize("requested", ["auto", "pallas"])
+def test_tpu_target_always_gets_compiled_kernels(requested, monkeypatch):
+    """A TPU target resolves to the compiled kernels whatever the host's
+    default device is; the oracle and the interpreter are refused there."""
+    monkeypatch.setenv("REPRO_QUANT_BACKEND", "jnp")
+    assert ops.wire_backend(requested, "tpu") == "pallas"
+    for refused in ("jnp", "interpret"):
+        with pytest.raises(ValueError, match="TPU"):
+            ops.wire_backend(refused, "tpu")
 
 
 # ---------------------------------------------------------------------------
@@ -262,9 +275,9 @@ def test_allreduce_ef_fused_matches_unfused_mesh8(mesh8):
         def f(xs, rs):
             return cl.allreduce_ef(xs, rs, ax, mean=True, backend="jnp",
                                    fused=fused)
-        w = compat.shard_map(f, mesh=mesh8, in_specs=(P(), P(ax)),
-                             out_specs=(P(), P(ax)), axis_names=set(ax),
-                             check_vma=False)
+        w = jax.shard_map(f, mesh=mesh8, in_specs=(P(), P(ax)),
+                          out_specs=(P(), P(ax)), axis_names=set(ax),
+                          check_vma=False)
         return w(x, res)
 
     o_f, r_f = run(True)
@@ -293,9 +306,9 @@ def test_hier_allreduce_ef_fused_matches_unfused_mesh8(mesh8):
 
         def f(xs, rs):
             return hier_lib.hier_allreduce_ef(xs, rs, spec, mean=True)
-        w = compat.shard_map(f, mesh=mesh8, in_specs=(P(), P(ax)),
-                             out_specs=(P(), P(ax)), axis_names=set(ax),
-                             check_vma=False)
+        w = jax.shard_map(f, mesh=mesh8, in_specs=(P(), P(ax)),
+                          out_specs=(P(), P(ax)), axis_names=set(ax),
+                          check_vma=False)
         return w(x, res)
 
     o_f, r_f = run(True)
@@ -319,9 +332,9 @@ def test_allreduce_int8_acc_folds_accumulate_mesh8(mesh8):
             return cl.allreduce(xs, ax, wire=cl.WIRE_INT8, mean=True,
                                 backend="jnp",
                                 acc=accs if use_acc else None)
-        w = compat.shard_map(f, mesh=mesh8, in_specs=(P(), P()),
-                             out_specs=P(), axis_names=set(ax),
-                             check_vma=False)
+        w = jax.shard_map(f, mesh=mesh8, in_specs=(P(), P()),
+                          out_specs=P(), axis_names=set(ax),
+                          check_vma=False)
         return w(x, acc)
 
     fused_out = run(True)

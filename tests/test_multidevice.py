@@ -24,14 +24,13 @@ def test_wire_formats_match_psum_across_8_ranks():
     _run(r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import collectives as cl
-mesh = compat.make_mesh((8,), ("data",), axis_types=(compat.AxisType.Auto,))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 4096)) * 1e-3
 def f(wire):
     def inner(u):
         return cl.allreduce(u[0], ("data",), wire=wire)
-    return jax.jit(compat.shard_map(inner, mesh=mesh, in_specs=P("data"),
+    return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=P("data"),
                          out_specs=P(), axis_names={"data"},
                          check_vma=False))(x)
 ref = np.asarray(jnp.sum(x, 0))
@@ -46,15 +45,14 @@ print("ok")
 def test_mlsl_8rank_training_matches_gspmd():
     _run(r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs import registry
 from repro.core.planner import Planner
 from repro.data import pipeline
 from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib
 from repro.train import trainer as tr
-mesh = compat.make_mesh((4, 2), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = registry.get_smoke_config("yi-6b")
 model = Model(cfg); opt = opt_lib.adamw(3e-3)
 planner = Planner(mesh=mesh)
@@ -62,7 +60,7 @@ dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=32, global_batch=8)
 results = {}
 for mode in ("gspmd", "mlsl"):
     comm = tr.CommConfig(mode=mode)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
         step = jax.jit(tr.make_train_step(model, opt, mesh, planner, comm))
         for raw in pipeline.iterate(dcfg, 3):
@@ -85,11 +83,10 @@ print("ok")
 def test_ep_moe_matches_gather_moe_8ranks():
     _run(r"""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_lib
-mesh = compat.make_mesh((2, 4), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 d, E = 16, 8
 cfg = MoEConfig(n_experts=E, top_k=2, d_ff=32, capacity_factor=8.0)
 key = jax.random.PRNGKey(0)
@@ -98,7 +95,7 @@ p = {"router": jax.random.normal(key, (d, E)),
      "w2": jax.random.normal(jax.random.fold_in(key, 2), (E, 32, d)) * .1,
      "w3": jax.random.normal(jax.random.fold_in(key, 3), (E, d, 32)) * .1}
 x = jax.random.normal(jax.random.fold_in(key, 4), (4, 8, d)) * .5
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ref, aux_ref = jax.jit(lambda p, x: moe_lib.moe_apply(p, x, cfg))(p, x)
     y_ep, aux_ep = jax.jit(lambda p, x: moe_lib.moe_apply_ep(
         p, x, cfg, act="silu", mesh=mesh, batch_axes=("data",)))(p, x)
@@ -113,11 +110,10 @@ def test_ep_int8_wgather_grads_flow():
     (a plain grad-of-round would silently zero the expert updates)."""
     _run(r'''
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_lib
-mesh = compat.make_mesh((2, 4), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 d, E = 16, 8
 cfg = MoEConfig(n_experts=E, top_k=2, d_ff=32, capacity_factor=8.0)
 key = jax.random.PRNGKey(0)
@@ -131,7 +127,7 @@ def loss(p, x, wire):
                                   batch_axes=("data",), fsdp_axes=("data",),
                                   wgather_wire=wire)
     return jnp.mean(y.astype(jnp.float32) ** 2)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     g_ref = jax.jit(jax.grad(loss), static_argnums=2)(p, x, "bf16")
     g_q = jax.jit(jax.grad(loss), static_argnums=2)(p, x, "int8")
 for k in ("w1", "w2", "w3"):

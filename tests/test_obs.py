@@ -252,11 +252,10 @@ def test_engine_stats_and_describe(mesh8):
 
 
 def test_measure_bucket_times_smoke(mesh8):
-    from repro import compat
     comm = eng.CommConfig(mode="mlsl", wire="int8", hier=True,
                           error_feedback=True)
     engine = eng.CommEngine.create(_tree(), comm, mesh8, DATA_AXES)
-    with compat.set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         times = obs_stats.measure_bucket_times(engine, mesh8, iters=1,
                                                warmup=1)
     assert len(times) == engine.plan.n_buckets
@@ -270,11 +269,9 @@ def test_bucket_timer_compile_once_sample_many(mesh8):
     bucket's region once, then repeated sample() calls stay cheap and keep
     producing a full positive per-bucket vector."""
     import time as _time
-
-    from repro import compat
     comm = eng.CommConfig(mode="mlsl", wire="int8", hier=True)
     engine = eng.CommEngine.create(_tree(), comm, mesh8, DATA_AXES)
-    with compat.set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         timer = engine.bucket_timer(mesh8)
         first = timer.sample(warmup=1)           # pays the compiles
         t0 = _time.perf_counter()

@@ -5,11 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.checkpoint import ckpt
 from repro.configs import registry
 from repro.core.api import Session
 from repro.data import pipeline
+from repro.launch import mesh as mesh_lib
 from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib
 from repro.serve.engine import Engine, EngineConfig
@@ -17,15 +17,14 @@ from repro.train import trainer as tr
 
 
 def test_full_pipeline(tmp_path):
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    mesh = mesh_lib.make_host_mesh()
     cfg = registry.get_smoke_config("yi-6b")
     model = Model(cfg)
     sess = Session.create(mesh, n_params=model.n_params(),
                           comm=tr.CommConfig(mode="mlsl", wire="bf16"))
     opt = opt_lib.adamw(3e-3)
     dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=32, global_batch=4)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.make_train_state(model, opt, jax.random.PRNGKey(0))
         step = jax.jit(sess.make_train_step(model, opt))
         first = last = None

@@ -5,10 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import registry
 from repro.core.planner import Planner
 from repro.data import pipeline
+from repro.launch import mesh as mesh_lib
 from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib
 from repro.train import trainer as tr
@@ -16,8 +16,7 @@ from repro.train import trainer as tr
 
 @pytest.fixture(scope="module")
 def mesh():
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    return mesh_lib.make_host_mesh()
 
 
 def _train(mesh, comm, steps=25, arch="yi-6b", seed=0):
@@ -27,9 +26,13 @@ def _train(mesh, comm, steps=25, arch="yi-6b", seed=0):
     planner = Planner(mesh=mesh)
     dcfg = pipeline.DataConfig(vocab=cfg.vocab, seq_len=48, global_batch=4,
                                seed=seed)
-    with compat.set_mesh(mesh):
-        state = tr.make_train_state(model, opt, jax.random.PRNGKey(seed))
-        step = jax.jit(tr.make_train_step(model, opt, mesh, planner, comm))
+    engine = (tr.make_comm_engine(model, mesh, planner, comm)
+              if comm.mode == "mlsl" else None)
+    with jax.set_mesh(mesh):
+        state = tr.make_train_state(model, opt, jax.random.PRNGKey(seed),
+                                    engine=engine)
+        step = jax.jit(tr.make_train_step(model, opt, mesh, planner, comm,
+                                          engine=engine))
         losses = []
         for raw in pipeline.iterate(dcfg, steps):
             batch = Batch(tokens=jnp.asarray(raw["tokens"]),
