@@ -353,12 +353,13 @@ class CommEngine:
         int8 wire the sum lands in the same pass that expands the wire
         payload, instead of a separate full-size read-add-write.
 
-        The whole message is wrapped in a `jax.named_scope` so XLA profiles
-        attribute device time to the named bucket + route (metadata only —
+        The message is wrapped in a `jax.named_scope` naming its route and
+        wire, inside the caller's `comm/bucket{bi}` scope, so XLA profiles
+        attribute device time to the bucket and route (metadata only —
         numerics and schedules are untouched)."""
         p = self.plan
         route = "hier" if p.algos[bi] == planner_lib.ALGO_HIER else "flat"
-        with jax.named_scope(f"bucket{bi}/{route}_allreduce_{p.wire}"):
+        with jax.named_scope(f"{route}_allreduce_{p.wire}"):
             if p.algos[bi] == planner_lib.ALGO_HIER:
                 if p.use_ef:
                     return hier_lib.hier_allreduce_ef(flat, residual,
@@ -373,6 +374,20 @@ class CommEngine:
             return cl.allreduce(flat, p.axes_for(bi), wire=p.wire,
                                 mean=True, backend=p.quant_backend,
                                 fused=p.fused_quant, acc=acc), None
+
+    def _reduce_leafwise(self, vals, bi: int, token):
+        """A model-sharded bucket, reduced leaf by leaf and shape-
+        preserving (the int8 wire's flatten/scatter composition would
+        reshard its leaves, so it takes the bf16 wire instead). Returns
+        (reduced leaves, token)."""
+        p = self.plan
+        wire = p.wire if p.wire != cl.WIRE_INT8 else cl.WIRE_BF16
+        with jax.named_scope(f"leafwise_allreduce_{wire}"):
+            vals = [cl.allreduce(v, p.axes_for(bi), wire=wire, mean=True)
+                    for v in vals]
+        if p.prioritize:
+            token = scheduler._token_of(vals[0])
+        return vals, token
 
     def reduce_chained(self, grads, residuals, token):
         """Fused, prioritized, wire-precision gradient exchange, continuing
@@ -392,6 +407,10 @@ class CommEngine:
         the chain spans microbatches, ordering microbatch k's reduction ahead
         of microbatch k+1's without tying it to k+1's compute.
         Returns (reduced_tree, new_residuals, token).
+
+        Device ops are named per bucket: `comm/bucket{bi}/pack` (fusion
+        into the message and the chain barrier), the message's route and
+        wire (`_reduce_bucket`), and `comm/bucket{bi}/unpack`.
         """
         p = self.plan
         if p.skip_reduce:
@@ -400,32 +419,32 @@ class CommEngine:
         new_leaves = list(leaves)
         new_residuals = []
         for bi, bucket in enumerate(p.buckets.buckets):
-            if p.fusable[bi]:
-                flat = scheduler.fuse_bucket(leaves, bucket)
-                if p.prioritize:
-                    flat, token = scheduler.chain_barrier(flat, token)
-                red, res = self._reduce_bucket(
-                    flat, residuals[bi] if p.use_ef else None, bi)
-                if p.use_ef:
-                    new_residuals.append(res)
-                if p.prioritize:
-                    token = scheduler._token_of(red)
-                for lid, leaf in scheduler.unfuse_bucket(red, bucket).items():
-                    new_leaves[lid] = leaf
-            else:
-                vals = [leaves[i] for i in bucket.leaf_ids]
-                if p.prioritize:
-                    vals, token = scheduler.chain_barrier(vals, token)
-                wire = p.wire if p.wire != cl.WIRE_INT8 else cl.WIRE_BF16
-                with jax.named_scope(f"bucket{bi}/leafwise_allreduce_{wire}"):
-                    vals = [cl.allreduce(v, p.axes_for(bi), wire=wire,
-                                         mean=True) for v in vals]
-                if p.use_ef:
-                    new_residuals.append(residuals[bi])
-                if p.prioritize:
-                    token = scheduler._token_of(vals[0])
-                for lid, leaf in zip(bucket.leaf_ids, vals):
-                    new_leaves[lid] = leaf
+            with jax.named_scope(f"comm/bucket{bi}"):
+                if p.fusable[bi]:
+                    with jax.named_scope("pack"):
+                        flat = scheduler.fuse_bucket(leaves, bucket)
+                        if p.prioritize:
+                            flat, token = scheduler.chain_barrier(flat, token)
+                    red, res = self._reduce_bucket(
+                        flat, residuals[bi] if p.use_ef else None, bi)
+                    if p.use_ef:
+                        new_residuals.append(res)
+                    with jax.named_scope("unpack"):
+                        if p.prioritize:
+                            token = scheduler._token_of(red)
+                        for lid, leaf in scheduler.unfuse_bucket(
+                                red, bucket).items():
+                            new_leaves[lid] = leaf
+                else:
+                    vals = [leaves[i] for i in bucket.leaf_ids]
+                    if p.prioritize:
+                        with jax.named_scope("pack"):
+                            vals, token = scheduler.chain_barrier(vals, token)
+                    vals, token = self._reduce_leafwise(vals, bi, token)
+                    if p.use_ef:
+                        new_residuals.append(residuals[bi])
+                    for lid, leaf in zip(bucket.leaf_ids, vals):
+                        new_leaves[lid] = leaf
         out = jax.tree_util.tree_unflatten(p.buckets.treedef, new_leaves)
         return out, (tuple(new_residuals) if p.use_ef else residuals), token
 
@@ -456,52 +475,51 @@ class CommEngine:
         dequantize (one pass); on float wires it is a plain add on the
         reduced message (still bucket-sized, never tree-shaped). Returns
         (new_acc, new_residuals, token) — unbucketed via `unfuse_accum`
-        after the last microbatch.
+        after the last microbatch. Device ops are named per bucket as in
+        `reduce_chained`.
         """
         p = self.plan
         leaves = jax.tree_util.tree_leaves(grads)
         new_acc = []
         new_residuals = []
         for bi, bucket in enumerate(p.buckets.buckets):
-            if p.fusable[bi]:
-                flat = scheduler.fuse_bucket(leaves, bucket)
-                if p.skip_reduce:
-                    new_acc.append(acc[bi] + flat)
+            with jax.named_scope(f"comm/bucket{bi}"):
+                if p.fusable[bi]:
+                    with jax.named_scope("pack"):
+                        flat = scheduler.fuse_bucket(leaves, bucket)
+                        if p.prioritize and not p.skip_reduce:
+                            flat, token = scheduler.chain_barrier(flat, token)
+                    if p.skip_reduce:
+                        new_acc.append(acc[bi] + flat)
+                        if p.use_ef:
+                            new_residuals.append(residuals[bi])
+                        continue
+                    red, res = self._reduce_bucket(
+                        flat, residuals[bi] if p.use_ef else None, bi,
+                        acc=acc[bi])
+                    if p.use_ef:
+                        new_residuals.append(res)
+                    if p.prioritize:
+                        token = scheduler._token_of(red)
+                    new_acc.append(red)
+                else:
+                    vals = [leaves[i] for i in bucket.leaf_ids]
+                    if p.skip_reduce:
+                        new_acc.append(tuple(
+                            a + v.astype(jnp.float32)
+                            for a, v in zip(acc[bi], vals)))
+                        if p.use_ef:
+                            new_residuals.append(residuals[bi])
+                        continue
+                    if p.prioritize:
+                        with jax.named_scope("pack"):
+                            vals, token = scheduler.chain_barrier(vals, token)
+                    vals, token = self._reduce_leafwise(vals, bi, token)
                     if p.use_ef:
                         new_residuals.append(residuals[bi])
-                    continue
-                if p.prioritize:
-                    flat, token = scheduler.chain_barrier(flat, token)
-                red, res = self._reduce_bucket(
-                    flat, residuals[bi] if p.use_ef else None, bi,
-                    acc=acc[bi])
-                if p.use_ef:
-                    new_residuals.append(res)
-                if p.prioritize:
-                    token = scheduler._token_of(red)
-                new_acc.append(red)
-            else:
-                vals = [leaves[i] for i in bucket.leaf_ids]
-                if p.skip_reduce:
                     new_acc.append(tuple(
                         a + v.astype(jnp.float32)
                         for a, v in zip(acc[bi], vals)))
-                    if p.use_ef:
-                        new_residuals.append(residuals[bi])
-                    continue
-                if p.prioritize:
-                    vals, token = scheduler.chain_barrier(vals, token)
-                wire = p.wire if p.wire != cl.WIRE_INT8 else cl.WIRE_BF16
-                with jax.named_scope(f"bucket{bi}/leafwise_allreduce_{wire}"):
-                    vals = [cl.allreduce(v, p.axes_for(bi), wire=wire,
-                                         mean=True) for v in vals]
-                if p.use_ef:
-                    new_residuals.append(residuals[bi])
-                if p.prioritize:
-                    token = scheduler._token_of(vals[0])
-                new_acc.append(tuple(
-                    a + v.astype(jnp.float32)
-                    for a, v in zip(acc[bi], vals)))
         return (tuple(new_acc),
                 (tuple(new_residuals) if p.use_ef else residuals), token)
 
@@ -513,9 +531,11 @@ class CommEngine:
         for bi, b in enumerate(p.buckets.buckets):
             if p.fusable[bi]:
                 off = 0
-                for lid, size, shape in zip(b.leaf_ids, b.sizes, b.shapes):
-                    leaves[lid] = acc[bi][off:off + size].reshape(shape)
-                    off += size
+                with jax.named_scope(f"comm/bucket{bi}/unpack"):
+                    for lid, size, shape in zip(b.leaf_ids, b.sizes,
+                                                b.shapes):
+                        leaves[lid] = acc[bi][off:off + size].reshape(shape)
+                        off += size
             else:
                 for lid, a in zip(b.leaf_ids, acc[bi]):
                     leaves[lid] = a

@@ -53,28 +53,6 @@ class SimLayer:
     wgrad_bytes: float
 
 
-@dataclasses.dataclass(frozen=True)
-class SimSpan:
-    """One interval of the modeled timeline (``record_timeline=True``).
-
-    Times are seconds from iteration start. ``cat`` is "compute" (fwd/bwd
-    work), "comm" (the network servicing a transfer — a preempted priority
-    transfer yields one span per serviced segment), or "stall" (compute
-    waiting on an unfinished allreduce — the exposed time, per layer).
-    ``obs.trace.export_sim_spans`` turns these into Chrome-trace events.
-    """
-
-    name: str
-    cat: str                    # "compute" | "comm" | "stall"
-    start: float
-    end: float
-    layer: int = -1
-
-    @property
-    def duration(self) -> float:
-        return self.end - self.start
-
-
 @dataclasses.dataclass
 class IterationStats:
     policy: Policy
@@ -83,7 +61,6 @@ class IterationStats:
     exposed_comm: float
     comm_busy: float            # seconds the link was transferring
     completion_times: list     # allreduce completion per layer index
-    timeline: list             # SimSpan intervals (record_timeline=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -190,20 +167,15 @@ def _allreduce_durations(layers: Sequence[SimLayer], p: int, link: hw.Link,
 def _serve_fifo(jobs: Sequence[_Job]):
     """Single network resource, service in ready (issue) order.
 
-    Returns (done, segments): per-job completion times plus the serviced
-    intervals as (job_index, start, end) — FIFO never preempts, so exactly
-    one segment per job.
+    Returns per-job completion times.
     """
     order = sorted(range(len(jobs)), key=lambda i: (jobs[i].ready, -jobs[i].layer))
     done = [0.0] * len(jobs)
-    segments = []
     t = 0.0
     for i in order:
-        start = max(t, jobs[i].ready)
-        t = start + jobs[i].duration
+        t = max(t, jobs[i].ready) + jobs[i].duration
         done[i] = t
-        segments.append((i, start, t))
-    return done, segments
+    return done
 
 
 def _serve_priority(jobs: Sequence[_Job]):
@@ -214,14 +186,11 @@ def _serve_priority(jobs: Sequence[_Job]):
     remaining bytes intact (MLSL 'completes preempted operations in an
     optimal manner as and when they are required').
 
-    Returns (done, segments): per-job completion times plus the serviced
-    intervals as (job_index, start, end) — a preempted job contributes one
-    segment per serviced stretch.
+    Returns per-job completion times.
     """
     n = len(jobs)
     remaining = [j.duration for j in jobs]
     done = [0.0] * n
-    segments = []
     arrivals = sorted(range(n), key=lambda i: jobs[i].ready)
     arrived: list = []          # layer-sorted list of not-yet-finished jobs
     t = 0.0
@@ -241,22 +210,18 @@ def _serve_priority(jobs: Sequence[_Job]):
         next_arrival = jobs[arrivals[ai]].ready if ai < n else float("inf")
         finish_at = t + remaining[cur]
         if finish_at <= next_arrival:
-            segments.append((cur, t, finish_at))
             t = finish_at
             done[cur] = t
             arrived.pop(0)
             finished += 1
         else:
-            if next_arrival > t:
-                segments.append((cur, t, next_arrival))
             remaining[cur] -= next_arrival - t
             t = next_arrival
-    return done, segments
+    return done
 
 
 def simulate_iteration(layers: Sequence[SimLayer], p: int, link: hw.Link,
                        policy: Policy = Policy.PRIORITY_OVERLAP,
-                       record_timeline: bool = False,
                        overlap_eff: float = 1.0,
                        topo: hw.Topology | None = None,
                        comm_algo: str = "auto",
@@ -294,67 +259,46 @@ def simulate_iteration(layers: Sequence[SimLayer], p: int, link: hw.Link,
                                      topo=topo, comm_algo=comm_algo,
                                      wire=wire, ef=ef,
                                      fused_quant=fused_quant)
-    timeline = []
-
-    def span(name, cat, start, end, layer=-1):
-        if record_timeline and end > start:
-            timeline.append(SimSpan(name=name, cat=cat, start=start,
-                                    end=end, layer=layer))
-
     if policy is Policy.BLOCKING:
         t = 0.0
         done = [0.0] * n
         for i in range(n - 1, -1, -1):
-            span(f"bwd:{layers[i].name}", "compute", t,
-                 t + layers[i].bwd_time * slow, layer=i)
             t += layers[i].bwd_time * slow
-            span(f"allreduce:{layers[i].name}", "comm", t,
-                 t + durations[i], layer=i)
             t += durations[i]          # synchronous allreduce, no overlap
             done[i] = t
         for i in range(n):
-            span(f"fwd:{layers[i].name}", "compute", t,
-                 t + layers[i].fwd_time * slow, layer=i)
             t += layers[i].fwd_time * slow
         total = t
         return IterationStats(policy=policy, total_time=total,
                               compute_time=compute,
                               exposed_comm=total - compute,
                               comm_busy=sum(durations),
-                              completion_times=done, timeline=timeline)
+                              completion_times=done)
 
     # --- overlapped policies -------------------------------------------------
     t = 0.0
     jobs = []
     for i in range(n - 1, -1, -1):
-        span(f"bwd:{layers[i].name}", "compute", t,
-             t + layers[i].bwd_time * slow, layer=i)
         t += layers[i].bwd_time * slow
         jobs.append(_Job(layer=i, ready=t, duration=durations[i]))
     bwd_end = t
     jobs = sorted(jobs, key=lambda j: j.layer)
     if policy is Policy.FIFO_OVERLAP:
-        done, segments = _serve_fifo(jobs)
+        done = _serve_fifo(jobs)
     else:
-        done, segments = _serve_priority(jobs)
-    for ji, start, end in segments:
-        span(f"allreduce:{layers[jobs[ji].layer].name}", "comm", start, end,
-             layer=jobs[ji].layer)
+        done = _serve_priority(jobs)
 
     t = bwd_end
     for i in range(n):
         # fwd(i) waits on allreduce(i): the wait IS the exposed time
-        span(f"stall:{layers[i].name}", "stall", t, done[i], layer=i)
         t = max(t, done[i])
-        span(f"fwd:{layers[i].name}", "compute", t,
-             t + layers[i].fwd_time * slow, layer=i)
         t += layers[i].fwd_time * slow
     total = t
     return IterationStats(policy=policy, total_time=total,
                           compute_time=compute,
                           exposed_comm=total - compute,
                           comm_busy=sum(durations),
-                          completion_times=done, timeline=timeline)
+                          completion_times=done)
 
 
 def scaling_efficiency(layers: Sequence[SimLayer], p: int, link: hw.Link,
@@ -418,12 +362,10 @@ class BucketScheduleStats:
     compute_time: float          # n_micro * per-microbatch fwd+bwd
     exposed_comm: float          # total - compute
     comm_busy: float             # n_micro * sum(bucket service times)
-    timeline: tuple = ()         # SimSpan intervals (record_timeline=True)
 
 
 def simulate_bucket_schedule(bucket_times: Sequence[float], n_micro: int,
-                             micro_compute: float, *, overlap: bool,
-                             record_timeline: bool = False
+                             micro_compute: float, *, overlap: bool
                              ) -> BucketScheduleStats:
     """Estimate one step of the CommEngine's accumulation-scan exchange.
 
@@ -438,51 +380,25 @@ def simulate_bucket_schedule(bucket_times: Sequence[float], n_micro: int,
 
     With ``n_micro == 1`` both schedules degrade to reduce-at-end and the
     full chain is exposed, matching the trainer's fallback.
-
-    ``record_timeline=True`` fills ``timeline`` with SimSpan intervals
-    (compute per microbatch, comm per bucket message, the end-of-step drain
-    as "stall") in the same span format as ``simulate_iteration`` —
-    ``obs.trace.export_sim_spans`` renders either.
     """
     comm_per_micro = float(sum(bucket_times))
     compute = n_micro * micro_compute
-    timeline = []
-
-    def span(name, cat, start, end, layer=-1):
-        if record_timeline and end > start:
-            timeline.append(SimSpan(name=name, cat=cat, start=start,
-                                    end=end, layer=layer))
-
     if not overlap or n_micro == 1:
         # blocking: microbatch k+1's compute gates on k's reduction chain
-        t = 0.0
-        for k in range(n_micro):
-            span(f"micro{k}/compute", "compute", t, t + micro_compute)
-            t += micro_compute
-            for bi, bt in enumerate(bucket_times):
-                span(f"micro{k}/bucket{bi}", "comm", t, t + bt, layer=bi)
-                t += bt
         total = compute + n_micro * comm_per_micro
     else:
         t_link = 0.0
         for k in range(n_micro):
-            span(f"micro{k}/compute", "compute", k * micro_compute,
-                 (k + 1) * micro_compute)
             ready = (k + 1) * micro_compute    # bwd of microbatch k done
-            for bi, t in enumerate(bucket_times):
-                start = max(t_link, ready)
-                span(f"micro{k}/bucket{bi}", "comm", start, start + t,
-                     layer=bi)
-                t_link = start + t
-        total = max(compute, t_link)
+            for t in bucket_times:
+                t_link = max(t_link, ready) + t
         # only the chain's drain past the last microbatch's compute is
-        # exposed: that wait is the step's stall
-        span("drain", "stall", compute, t_link)
+        # exposed
+        total = max(compute, t_link)
     return BucketScheduleStats(overlap=overlap, n_micro=n_micro,
                                total_time=total, compute_time=compute,
                                exposed_comm=total - compute,
-                               comm_busy=n_micro * comm_per_micro,
-                               timeline=tuple(timeline))
+                               comm_busy=n_micro * comm_per_micro)
 
 
 # --------------------------------------------------------------------------

@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator, Optional
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -66,10 +67,13 @@ def _memmap_batch(cfg: DataConfig, step: int, rows: slice) -> np.ndarray:
 
 def batch_at(cfg: DataConfig, step: int, *, n_hosts: int = 1,
              host_id: int = 0) -> dict:
-    """The (host-local) training batch for a global step: tokens + labels."""
+    """The (host-local) training batch for a global step: tokens + labels.
+    Made inside a `data/batch` span on the profiler's clock, so a device
+    idle gap in a trace can be put down to input work."""
     rows = host_slice(cfg.global_batch, n_hosts, host_id)
     fn = _synthetic_batch if cfg.kind == "synthetic" else _memmap_batch
-    tokens = fn(cfg, step, rows)
+    with jax.profiler.TraceAnnotation("data/batch"):
+        tokens = fn(cfg, step, rows)
     return {"tokens": tokens, "labels": tokens}
 
 

@@ -123,6 +123,7 @@ def quantize_blocks(x2d: jax.Array, *, interpret: bool = False):
     _check_block(x2d.shape)
     q, s = pl.pallas_call(
         _quantize_kernel,
+        name="quantize_blocks",
         grid=_grid(n_blocks),
         in_specs=[pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0))],
         out_specs=[
@@ -164,6 +165,7 @@ def quantize_ef_blocks(x2d: jax.Array, res2d: jax.Array, *,
             f"shape {x2d.shape}")
     q, s, nr = pl.pallas_call(
         _quantize_ef_kernel,
+        name="quantize_ef_blocks",
         grid=_grid(n_blocks),
         in_specs=[
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
@@ -192,6 +194,7 @@ def dequantize_blocks(q2d: jax.Array, scales: jax.Array, *,
     _check_block(q2d.shape)
     return pl.pallas_call(
         functools.partial(_dequantize_kernel, out_dtype=out_dtype),
+        name="dequantize_blocks",
         grid=_grid(n_blocks),
         in_specs=[
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
@@ -211,6 +214,7 @@ def dequantize_accumulate_blocks(q2d: jax.Array, scales: jax.Array,
     _check_block(q2d.shape)
     return pl.pallas_call(
         functools.partial(_dequant_accum_kernel, out_dtype=out_dtype),
+        name="dequantize_accumulate_blocks",
         grid=_grid(n_blocks),
         in_specs=[
             pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),

@@ -92,10 +92,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0)
     # observability (repro.obs): --stats prints the MLSL-style per-bucket
     # CommStats table + step meter and writes them into the perf ledger
-    # (BENCH_comm_stats.json in $BENCH_DIR); --trace DIR writes a Chrome-
-    # trace JSON (DIR/trace.json, Perfetto-loadable) with measured step +
-    # per-bucket spans beside the modeled schedule for the same config.
-    # Both block on every step's result to time it (small overhead).
+    # (BENCH_comm_stats.json in $BENCH_DIR); it blocks on every step's
+    # result to time it (small overhead). --trace DIR takes a jax.profiler
+    # trace of the training loop into DIR: the device ops under the step's
+    # layer scopes (model, optim/, comm/) and the host spans (train steps,
+    # data/batch) on one clock, for TensorBoard's profiler or Perfetto.
     ap.add_argument("--stats", action="store_true")
     ap.add_argument("--trace", default=None, metavar="DIR")
     # streaming telemetry + online health monitor (repro.obs.telemetry /
@@ -177,15 +178,10 @@ def run(cfg: ModelConfig, args: argparse.Namespace) -> RunResult:
     engine = (tr.make_comm_engine(model, mesh, planner, comm)
               if args.comm == "mlsl" else None)
 
-    meter = tracer = None
-    if args.stats or args.trace or args.telemetry:
+    meter = None
+    if args.stats or args.telemetry:
         from repro.obs import meter as obs_meter
-        from repro.obs import trace as obs_trace
         meter = obs_meter.StepMeter(tokens_per_step=args.batch * args.seq)
-        if args.trace:
-            tracer = obs_trace.TraceWriter()
-            tracer.name_process(0, "measured")
-            tracer.name_thread(0, 0, "train steps")
 
     telem = monitor = timer = None
     t_model_tel: list = []
@@ -257,19 +253,16 @@ def run(cfg: ModelConfig, args: argparse.Namespace) -> RunResult:
         compile_s = time.perf_counter() - t0
         print(f"compiled step in {compile_s:.1f}s", flush=True)
         history = []
+        if args.trace:
+            jax.profiler.start_trace(args.trace)
         t0 = time.perf_counter()
         for s, batch in enumerate(itertools.chain([first], batches)):
             if meter is not None:
                 # metering blocks on each step's result (async dispatch would
-                # attribute step k's time to k+1); span per step when tracing
+                # attribute step k's time to k+1)
                 meter.start()
-                if tracer is not None:
-                    with tracer.span(f"step{s}", cat="step"):
-                        state, metrics = step_fn(state, batch)
-                        jax.block_until_ready(metrics)
-                else:
-                    state, metrics = step_fn(state, batch)
-                    jax.block_until_ready(metrics)
+                state, metrics = _step(step_fn, state, batch, s)
+                jax.block_until_ready(metrics)
                 meter.update(loss=float(metrics["loss"]),
                              grad_norm=float(metrics["grad_norm"]))
                 if t_model_tel:
@@ -281,11 +274,6 @@ def run(cfg: ModelConfig, args: argparse.Namespace) -> RunResult:
                             meter.step_time / n_micro,
                             overlap=comm.overlap).exposed_comm
                 exposed = meter.exposed_comm_frac
-                if tracer is not None:
-                    vals = {"tokens_per_sec": meter.tokens_per_sec}
-                    if exposed is not None:
-                        vals["exposed_comm_share"] = exposed
-                    tracer.counter("rates", tracer.now_us(), vals)
                 if telem is not None:
                     telem.step(step=s, t_step_s=meter.last_dt,
                                tok_s=meter.tokens_per_sec,
@@ -307,7 +295,7 @@ def run(cfg: ModelConfig, args: argparse.Namespace) -> RunResult:
                                     factor=a.factor, level=a.level,
                                     rank=a.rank, detail=a.detail)
             else:
-                state, metrics = step_fn(state, batch)
+                state, metrics = _step(step_fn, state, batch, s)
             if s % args.log_every == 0 or s == args.steps - 1:
                 loss, gnorm = (float(metrics["loss"]),
                                float(metrics["grad_norm"]))
@@ -318,15 +306,26 @@ def run(cfg: ModelConfig, args: argparse.Namespace) -> RunResult:
                 else:
                     print(f"step {s:5d} loss {loss:.4f} gnorm {gnorm:.3f} "
                           f"({t - t0:.1f}s)", flush=True)
-        if args.stats or tracer is not None:
+        if args.trace:
+            jax.block_until_ready(state)
+            jax.profiler.stop_trace()
+            print(f"trace: {args.trace} (jax.profiler xplane)")
+        if args.stats:
             _emit_observability(args, mesh, planner, comm, model, meter,
-                                tracer, engine=engine)
+                                engine=engine)
         if telem is not None:
             telem.close()
             print(f"telemetry: {telem.path} ({telem.n_records} records)")
             _report_health(monitor)
     return RunResult(state=state, history=history, compiled=compiled,
                      compile_s=compile_s, n_compiles=step_fn._cache_size())
+
+
+def _step(step_fn, state, batch, s: int):
+    """One training step, marked in a profiler trace as step `s` of
+    `train`."""
+    with jax.profiler.StepTraceAnnotation("train", step_num=s):
+        return step_fn(state, batch)
 
 
 def _report_health(monitor) -> None:
@@ -341,20 +340,17 @@ def _report_health(monitor) -> None:
             print(f"    -> {monitor.reroute(a).summary()}")
 
 
-def _emit_observability(args, mesh, planner, comm, model, meter, tracer,
+def _emit_observability(args, mesh, planner, comm, model, meter,
                         engine=None):
-    """Post-run stats/trace emission (--stats / --trace).
+    """Post-run stats (--stats).
 
     For the mlsl data path: replay each bucket's exchange standalone to get
-    measured per-bucket service times, print the CommStats table, write the
-    comm_stats entries into the perf ledger (BENCH_comm_stats.json — all
-    informational/unstable, never gated), and lay measured per-bucket spans
-    plus the MODELED bucket schedule for the same config side by side in
-    the trace so Perfetto shows measured-vs-modeled in one view.
+    measured per-bucket service times, print the CommStats table and write
+    the comm_stats entries into the perf ledger (BENCH_comm_stats.json —
+    all informational/unstable, never gated).
     """
     from repro.core import simulator as sim
     from repro.obs import stats as obs_stats
-    from repro.obs import trace as obs_trace
 
     st = None
     if args.comm == "mlsl":
@@ -362,16 +358,6 @@ def _emit_observability(args, mesh, planner, comm, model, meter, tracer,
             engine = tr.make_comm_engine(model, mesh, planner, comm)
         measured = obs_stats.measure_bucket_times(engine, mesh, iters=2)
         st = engine.stats(measured=measured)
-        if tracer is not None:
-            tracer.name_thread(0, 1, "bucket replay")
-            t_us = tracer.now_us()
-            for b in st.buckets:
-                dur = (b.t_measured or 0.0) * 1e6
-                tracer.complete(
-                    f"bucket{b.index}/{b.route}_allreduce_{b.wire}",
-                    t_us, dur, pid=0, tid=1, cat="comm",
-                    args={"elems": b.n_elems, "total_B": b.total_bytes})
-                t_us += dur
         # the modeled schedule for this config: per-bucket cost-model times
         # through the engine's own microbatch pipeline, at the measured
         # compute scale when a meter ran
@@ -380,38 +366,28 @@ def _emit_observability(args, mesh, planner, comm, model, meter, tracer,
                          if meter is not None and meter.steps else 1e-3)
         modeled = sim.simulate_bucket_schedule(
             [b.t_model or 0.0 for b in st.buckets], n_micro, micro_compute,
-            overlap=comm.overlap, record_timeline=True)
+            overlap=comm.overlap)
         if meter is not None:
             meter.exposed_comm_model = modeled.exposed_comm
-        if tracer is not None:
-            obs_trace.export_sim_spans(modeled.timeline, tracer, pid=1,
-                                       track=f"modeled ({st.topo_name})")
-        if args.stats:
-            print(st.table())
-    elif args.stats:
+        print(st.table())
+    else:
         print("stats: per-bucket CommStats need --comm mlsl (gspmd's "
               "reductions are partitioner-inserted, not bucket messages)")
-    if args.stats and meter is not None and meter.steps:
+    if meter is not None and meter.steps:
         print(meter.summary())
 
-    if args.stats:
-        try:
-            from benchmarks import common as bench_common
-        except ImportError:
-            bench_common = None     # repo root not on sys.path
-        if bench_common is not None:
-            led = bench_common.Ledger("comm_stats")
-            for m in (st.to_metrics() if st is not None else []):
+    try:
+        from benchmarks import common as bench_common
+    except ImportError:
+        bench_common = None     # repo root not on sys.path
+    if bench_common is not None:
+        led = bench_common.Ledger("comm_stats")
+        for m in (st.to_metrics() if st is not None else []):
+            led.record(**m)
+        if meter is not None and meter.steps:
+            for m in meter.to_metrics():
                 led.record(**m)
-            if meter is not None and meter.steps:
-                for m in meter.to_metrics():
-                    led.record(**m)
-            print(f"stats ledger: {led.write()}")
-
-    if tracer is not None:
-        os.makedirs(args.trace, exist_ok=True)
-        path = tracer.write(os.path.join(args.trace, "trace.json"))
-        print(f"trace: {path} (open in https://ui.perfetto.dev)")
+        print(f"stats ledger: {led.write()}")
 
 
 if __name__ == "__main__":

@@ -1,14 +1,12 @@
-"""Observability: MLSL-style comm stats, Chrome-trace timelines, step meter.
+"""Observability: MLSL-style comm stats, a host trace writer, step meter.
 
 MLSL's proof points (paper §4) are per-message statistics — bytes, algorithm,
 exposed vs overlapped time — that only the library owning the exchange can
 produce. This subpackage is that accounting layer for the reproduction:
 
   repro.obs.trace  -- Chrome-trace-event (Perfetto-compatible) writer with
-                      host-side span helpers and an exporter for the
-                      simulator's modeled span timeline, so a measured mesh
-                      run and a modeled iteration open side by side in one
-                      Perfetto view.
+                      host-side span helpers (the serving CLI's
+                      timeline; training traces come from jax.profiler).
   repro.obs.stats  -- CommStats: the per-bucket wire-byte / route / modeled-
                       vs-measured-time report derived from an EnginePlan
                       (surfaced as EnginePlan.describe() / CommEngine.stats()

@@ -1,16 +1,15 @@
 """Chrome-trace-event timeline writer (Perfetto / chrome://tracing format).
 
-One ``TraceWriter`` collects events from any mix of sources — host-side
-``span()`` context managers around real work, measured per-bucket replay
-durations, and the simulator's modeled span timeline
-(``export_sim_spans``) — and writes a single JSON object file
+One ``TraceWriter`` collects host-side events — ``span()`` context
+managers around real work, counters, instants — and writes a single JSON
+object file
 
     {"traceEvents": [...], "displayTimeUnit": "ms", ...}
 
-loadable in https://ui.perfetto.dev. Tracks are labeled through process/
-thread metadata events, so a measured mesh run (pid 0) and the modeled
-iteration for the same config (pid 1) open side by side in one view — the
-visual form of the repo's measured-vs-modeled story.
+loadable in https://ui.perfetto.dev. The serving CLI (``launch/serve.py``)
+writes its prefill/decode timeline with it; the training CLI takes a
+``jax.profiler`` trace instead (``launch/train.py --trace``), which puts the
+device ops and the host spans on one clock.
 
 Timestamps are microseconds. All spans are emitted as complete ("X")
 events, which Perfetto nests by containment, so writers never need to
@@ -28,7 +27,7 @@ from __future__ import annotations
 import contextlib
 import json
 import time
-from typing import Iterable, Optional
+from typing import Optional
 
 # microseconds per second: Chrome trace ts/dur are in us
 _US = 1e6
@@ -137,37 +136,6 @@ class TraceWriter:
             json.dump(obj, fh, indent=1)
             fh.write("\n")
         return path
-
-
-# --------------------------------------------------------------------------
-# modeled-timeline export (repro.core.simulator span timelines)
-# --------------------------------------------------------------------------
-
-# one viewer row per span category, in a stable order
-_CAT_TIDS = {"compute": 0, "comm": 1, "stall": 2}
-
-
-def export_sim_spans(spans: Iterable, writer: TraceWriter, *, pid: int = 1,
-                     track: str = "modeled", t0_us: float = 0.0) -> int:
-    """Export a simulator span timeline into `writer`.
-
-    `spans` is any iterable of objects with ``name`` / ``cat`` / ``start`` /
-    ``end`` attributes and times in SECONDS (``simulator.SimSpan``:
-    ``IterationStats.timeline`` / ``BucketScheduleStats.timeline`` with
-    ``record_timeline=True``). Events land on `pid` with one thread row per
-    category (compute / comm / stall), offset by `t0_us` so a modeled
-    iteration can be laid next to a measured one. Returns the number of
-    span events written.
-    """
-    writer.name_process(pid, track)
-    n = 0
-    for s in spans:
-        tid = _CAT_TIDS.get(s.cat, len(_CAT_TIDS))
-        writer.name_thread(pid, tid, s.cat)
-        writer.complete(s.name, t0_us + s.start * _US,
-                        (s.end - s.start) * _US, pid=pid, tid=tid, cat=s.cat)
-        n += 1
-    return n
 
 
 # --------------------------------------------------------------------------

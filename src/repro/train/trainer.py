@@ -228,7 +228,11 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         loss_kw["tp_axis"] = tp_axis
 
     def loss_fn(params, batch: Batch):
-        return model.loss(params, batch, **loss_kw)
+        # profile attribution: forward ops read `jvp(model)`, backward ops
+        # `transpose(jvp(model))`, the forward recomputed under remat
+        # `rematted_computation`
+        with jax.named_scope("model"):
+            return model.loss(params, batch, **loss_kw)
 
     def _split_micro(batch, acc):
         def split(x):
@@ -413,8 +417,9 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
                     pending, bacc, residuals, token)
 
         gsum = engine.unfuse_accum(bacc)
-        grads = jax.tree_util.tree_map(
-            lambda g, pp: (g / acc).astype(pp.dtype), gsum, params)
+        with jax.named_scope("optim/cast"):
+            grads = jax.tree_util.tree_map(
+                lambda g, pp: (g / acc).astype(pp.dtype), gsum, params)
         return lsum / acc, grads, residuals
 
     # shard_map specs: manual over batch axes only; model axis stays auto.
@@ -422,7 +427,10 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
     replicated = P()
 
     def inner(params, opt_state, step, residuals, batch: Batch):
-        # per-device local loss; gradient = d(local mean)/d(params)
+        # per-device local loss; gradient = d(local mean)/d(params). Device
+        # ops carry the layer's scope: `model`, `comm/` (the engine) and
+        # `optim/` (everything from the reduced gradients to the new
+        # parameters)
         if comm.accum_steps > 1:
             loss, grads, residuals = accum_reduce(params, batch, residuals)
         else:
@@ -433,11 +441,15 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
             # XLA's excess-precision rewrite would otherwise hold the f32
             # messages as the tree the global norm waits on (3.5 GB at
             # Yi-6B widths on one chip)
-            grads = jax.lax.optimization_barrier(jax.tree_util.tree_map(
-                lambda g, pp: g.astype(pp.dtype), grads, params))
-        grads, gnorm = clip_grads(grads, grad_clip)
+            with jax.named_scope("optim/cast"):
+                grads = jax.lax.optimization_barrier(jax.tree_util.tree_map(
+                    lambda g, pp: g.astype(pp.dtype), grads, params))
+        with jax.named_scope("optim/clip"):
+            grads, gnorm = clip_grads(grads, grad_clip)
         loss = jax.lax.pmean(loss, data_axes)
-        params, opt_state = optimizer.update(grads, opt_state, params, step)
+        with jax.named_scope("optim/update"):
+            params, opt_state = optimizer.update(grads, opt_state, params,
+                                                 step)
         return params, opt_state, residuals, loss, gnorm
 
     grad_treedef = engine.plan.buckets.treedef

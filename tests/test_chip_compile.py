@@ -100,3 +100,33 @@ def test_engine_on_v5e_mesh_plans_compiled_kernels(topo, monkeypatch):
     engine = eng.CommEngine.create(grads, comm, mesh, ("node", "local"))
     assert engine.plan.quant_backend == "pallas"
     assert engine.plan.hier_spec.backend == "pallas"
+
+
+# each kernel's operand dtypes ("scales": the f32 (n_blocks,) scale vector)
+KERNEL_ARGS = {
+    "quantize_blocks": (jnp.float32,),
+    "quantize_ef_blocks": (jnp.float32, jnp.float32),
+    "dequantize_blocks": (jnp.int8, "scales"),
+    "dequantize_accumulate_blocks": (jnp.int8, "scales", jnp.float32),
+}
+
+
+@pytest.mark.parametrize("kernel", list(KERNEL_ARGS))
+def test_kernel_keeps_its_name_under_any_caller(one_chip, kernel):
+    """The compiled custom call, which is what the chip's trace names, is
+    called after the kernel however the function around it is named: a
+    trace reader that matches the four names keeps finding the kernels."""
+    import re
+    n = 64
+    args = [_arg((n,), jnp.float32, one_chip) if d == "scales"
+            else _arg((n, BLOCK), d, one_chip) for d in KERNEL_ARGS[kernel]]
+    raw = getattr(quant8, kernel).__wrapped__
+
+    @jax.jit
+    def some_caller(*xs):
+        return raw(*xs)
+
+    text = _compiled_text(some_caller, *args)
+    calls = re.findall(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert [re.sub(r"\.\d+$", "", c) for c in calls] == [kernel], calls

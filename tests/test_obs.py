@@ -4,8 +4,6 @@ The PR-9 acceptance claims verified here:
 
   * the Chrome-trace writer round-trips through write/load/validate and
     nested host spans stay containment-nested;
-  * ``export_sim_spans`` carries the simulator's modeled timeline into the
-    trace losslessly (span count, per-category totals == IterationStats);
   * ``CommEngine.stats()`` wire bytes exactly match the plan's message
     sizes x wire widths — flat fp32 is ``n_elems * 4`` unpadded, the
     hierarchical int8 fabric gather leg is ``elems * 1`` plus one f32
@@ -20,11 +18,9 @@ import json
 import jax
 import pytest
 
-from repro.configs import cnn_tables
 from repro.core import collectives as cl
 from repro.core import engine as eng
-from repro.core import hier, hw, planner
-from repro.core import simulator as sim
+from repro.core import hier, planner
 from repro.obs import meter as obs_meter
 from repro.obs import stats as obs_stats
 from repro.obs import trace as obs_trace
@@ -114,54 +110,6 @@ def test_trace_counter_round_trip(tmp_path):
     ):
         with pytest.raises(ValueError):
             obs_trace.validate_trace({"traceEvents": [bad]})
-
-
-# --------------------------------------------------------------------------
-# modeled-timeline export
-# --------------------------------------------------------------------------
-
-def _sim_stats(policy):
-    layers = sim.layers_from_specs(cnn_tables.TOPOLOGIES["resnet50"](), 32,
-                                   hw.XEON_6148)
-    return sim.simulate_iteration(layers, 8, hw.ETH_10G, policy,
-                                  record_timeline=True)
-
-
-@pytest.mark.parametrize("policy", list(sim.Policy))
-def test_export_sim_spans_matches_iteration_stats(policy):
-    st = _sim_stats(policy)
-    assert st.timeline, "record_timeline must fill the timeline"
-    w = obs_trace.TraceWriter()
-    n = obs_trace.export_sim_spans(st.timeline, w, pid=1, track="modeled")
-    assert n == len(st.timeline)
-    xs = [e for e in w.events if e["ph"] == "X"]
-    assert len(xs) == n and all(e["pid"] == 1 for e in xs)
-    # per-category span totals reproduce the IterationStats accounting
-    def total(cat):
-        return sum(e["dur"] for e in xs if e["cat"] == cat) / 1e6
-
-    assert total("compute") == pytest.approx(st.compute_time, rel=1e-9)
-    assert total("comm") == pytest.approx(st.comm_busy, rel=1e-9)
-    end = max(e["ts"] + e["dur"] for e in xs) / 1e6
-    assert end == pytest.approx(st.total_time, rel=1e-9)
-    obs_trace.validate_trace(w.to_json())
-
-
-@pytest.mark.parametrize("overlap", [False, True])
-def test_export_bucket_schedule_timeline(overlap):
-    st = sim.simulate_bucket_schedule([1e-3, 2e-3], 4, 5e-3, overlap=overlap,
-                                      record_timeline=True)
-    assert st.timeline
-    w = obs_trace.TraceWriter()
-    obs_trace.export_sim_spans(st.timeline, w)
-    xs = [e for e in w.events if e["ph"] == "X"]
-    comm = sum(e["dur"] for e in xs if e["cat"] == "comm") / 1e6
-    assert comm == pytest.approx(st.comm_busy, rel=1e-9)
-    end = max(e["ts"] + e["dur"] for e in xs) / 1e6
-    assert end == pytest.approx(st.total_time, rel=1e-9)
-    # no timeline unless asked: the default stays allocation-free
-    off = sim.simulate_bucket_schedule([1e-3], 2, 5e-3, overlap=overlap)
-    assert off.timeline == ()
 
 
 # --------------------------------------------------------------------------
