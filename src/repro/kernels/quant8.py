@@ -7,9 +7,13 @@ optionally accumulated) after the collective.
 
 TPU mapping: the gradient bucket is viewed as (n_blocks, block) with
 block a multiple of 128 (lane width) so each VMEM tile is MXU/VPU aligned.
-The grid walks row-tiles of TILE_ROWS blocks; abs-max reduction, scaling and
-rounding all happen inside VMEM, one HBM round-trip total -- on CPU the same
-kernels run under interpret=True and are validated against ref.py.
+Callers pad n_blocks to a multiple of TILE_ROWS; the grid walks row tiles of
+`tile_rows(n_blocks)` blocks (up to MAX_TILE_ROWS, the last tile ragged), so
+a grid step moves a few MB and its fixed cost stays small against its
+bytes. Every row is independent: the rows of a ragged tile past the end are
+never written back. Abs-max reduction, scaling and rounding all happen
+inside VMEM, one HBM round-trip total -- on CPU the same kernels run under
+interpret=True and are validated against ref.py.
 
 Fused wire hot path (one HBM read + one write of the gradient per leg):
 
@@ -21,9 +25,9 @@ Fused wire hot path (one HBM read + one write of the gradient per leg):
   * ``dequantize_accumulate_blocks`` -- acc + q * s on the gather side, so
     microbatch gradient accumulation consumes the int8 message directly.
 
-A bf16 tile rides the f32 (TILE_ROWS x block) tiling quantum: block is a
-multiple of 128 lanes and sub-native sublane tiles are masked by Mosaic, so
-one grid layout serves every input dtype and callers pad once.
+One grid layout serves every input dtype: a tile of MAX_TILE_ROWS rows is
+a whole number of native sublane tiles for f32, bf16 and int8 alike, and a
+buffer smaller than that is one tile spanning the whole array.
 """
 
 from __future__ import annotations
@@ -37,17 +41,28 @@ from jax.experimental import pallas as pl
 # Lane width on TPU is 128; sublane granularity for fp32 is 8.
 LANE = 128
 DEFAULT_BLOCK = 512          # elements per quantization block (multiple of 128)
-TILE_ROWS = 8                # quantization blocks handled per grid step
+TILE_ROWS = 8                # padding quantum: n_blocks is a multiple of it
+# Most quantization blocks one grid step takes. The per-block scales are a
+# lane-dense (n_blocks,) f32 vector, which XLA tiles by 1024 elements, and a
+# rank-1 block must span whole tiles of it (or the whole vector).
+MAX_TILE_ROWS = 1024
 
-# Mosaic takes a rank-1 block only when it spans a multiple of 128 elements,
-# so inside the kernels the per-block scales are an (n_blocks, 1) column
-# tiled (TILE_ROWS, 1). The jitted wrappers below reshape at their boundary:
-# callers see (n_blocks,) scales.
-_SCALE_SPEC = pl.BlockSpec((TILE_ROWS, 1), lambda i: (i, 0))
+
+def tile_rows(n_blocks: int) -> int:
+    """Quantization blocks per grid step for an (n_blocks, block) buffer:
+    all of a small buffer, else MAX_TILE_ROWS with a ragged last tile."""
+    return min(n_blocks, MAX_TILE_ROWS)
+
+
+def _specs(n_blocks: int, block: int) -> tuple:
+    """(row-tile spec, scale-vector spec) of one grid step."""
+    tile = tile_rows(n_blocks)
+    return (pl.BlockSpec((tile, block), lambda i: (i, 0)),
+            pl.BlockSpec((tile,), lambda i: (i,)))
 
 
 def _quantize_kernel(x_ref, q_ref, s_ref):
-    """One tile: (TILE_ROWS, block) float -> int8 + per-row scale.
+    """One tile: (rows, block) float -> int8 + per-row scale.
 
     The input cast to f32 happens on the VMEM tile, so a bf16 wire buffer is
     consumed directly (no materialized f32 copy in HBM)."""
@@ -57,7 +72,7 @@ def _quantize_kernel(x_ref, q_ref, s_ref):
     safe = jnp.where(scale > 0.0, scale, 1.0)
     q = jnp.clip(jnp.round(x / safe), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale
+    s_ref[...] = scale.reshape(s_ref.shape)
 
 
 def _quantize_ef_kernel(x_ref, r_ref, q_ref, s_ref, nr_ref):
@@ -76,20 +91,25 @@ def _quantize_ef_kernel(x_ref, r_ref, q_ref, s_ref, nr_ref):
     safe = jnp.where(scale > 0.0, scale, 1.0)
     q = jnp.clip(jnp.round(y / safe), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale
+    s_ref[...] = scale.reshape(s_ref.shape)
     nr_ref[...] = y - q * scale
+
+
+def _column(s_ref):
+    """The tile's lane-dense scales as a (rows, 1) column."""
+    return s_ref[...].reshape(s_ref.shape[0], 1)
 
 
 def _dequantize_kernel(q_ref, s_ref, o_ref, *, out_dtype):
     q = q_ref[...].astype(jnp.float32)
-    o_ref[...] = (q * s_ref[...]).astype(out_dtype)
+    o_ref[...] = (q * _column(s_ref)).astype(out_dtype)
 
 
 def _dequant_accum_kernel(q_ref, s_ref, acc_ref, o_ref, *, out_dtype):
     """Fused dequantize + accumulate: o = acc + q * s (error-feedback path)."""
     q = q_ref[...].astype(jnp.float32)
     acc = acc_ref[...].astype(jnp.float32)
-    o_ref[...] = (acc + q * s_ref[...]).astype(out_dtype)
+    o_ref[...] = (acc + q * _column(s_ref)).astype(out_dtype)
 
 
 def _grid(n_blocks: int) -> tuple:
@@ -100,7 +120,7 @@ def _grid(n_blocks: int) -> tuple:
             f"n_blocks={n_blocks} is not a multiple of the row-tile quantum "
             f"TILE_ROWS={TILE_ROWS}; pad the flat buffer to a multiple of "
             f"TILE_ROWS * block elements (see repro.kernels.ops._to_blocks)")
-    return (n_blocks // TILE_ROWS,)
+    return (pl.cdiv(n_blocks, tile_rows(n_blocks)),)
 
 
 def _check_block(shape: tuple) -> None:
@@ -121,22 +141,19 @@ def quantize_blocks(x2d: jax.Array, *, interpret: bool = False):
     """
     n_blocks, block = x2d.shape
     _check_block(x2d.shape)
-    q, s = pl.pallas_call(
+    row_spec, scale_spec = _specs(n_blocks, block)
+    return pl.pallas_call(
         _quantize_kernel,
         name="quantize_blocks",
         grid=_grid(n_blocks),
-        in_specs=[pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            _SCALE_SPEC,
-        ],
-        out_shape=[
+        in_specs=[row_spec],
+        out_specs=(row_spec, scale_spec),
+        out_shape=(
             jax.ShapeDtypeStruct((n_blocks, block), jnp.int8),
-            jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
-        ],
+            jax.ShapeDtypeStruct((n_blocks,), jnp.float32),
+        ),
         interpret=interpret,
     )(x2d)
-    return q, s.reshape(n_blocks)
 
 
 # The wire cast is folded into the quantize tile (`_quantize_kernel` casts on
@@ -163,28 +180,21 @@ def quantize_ef_blocks(x2d: jax.Array, res2d: jax.Array, *,
         raise ValueError(
             f"residual shape {res2d.shape} must match the blocked input "
             f"shape {x2d.shape}")
-    q, s, nr = pl.pallas_call(
+    row_spec, scale_spec = _specs(n_blocks, block)
+    return pl.pallas_call(
         _quantize_ef_kernel,
         name="quantize_ef_blocks",
         grid=_grid(n_blocks),
-        in_specs=[
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            _SCALE_SPEC,
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-        ],
-        out_shape=[
+        in_specs=[row_spec, row_spec],
+        out_specs=(row_spec, scale_spec, row_spec),
+        out_shape=(
             jax.ShapeDtypeStruct((n_blocks, block), jnp.int8),
-            jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks,), jnp.float32),
             jax.ShapeDtypeStruct((n_blocks, block), jnp.float32),
-        ],
+        ),
         input_output_aliases={1: 2},
         interpret=interpret,
     )(x2d, res2d)
-    return q, s.reshape(n_blocks), nr
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -192,18 +202,16 @@ def dequantize_blocks(q2d: jax.Array, scales: jax.Array, *,
                       out_dtype=jnp.float32, interpret: bool = False):
     n_blocks, block = q2d.shape
     _check_block(q2d.shape)
+    row_spec, scale_spec = _specs(n_blocks, block)
     return pl.pallas_call(
         functools.partial(_dequantize_kernel, out_dtype=out_dtype),
         name="dequantize_blocks",
         grid=_grid(n_blocks),
-        in_specs=[
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            _SCALE_SPEC,
-        ],
-        out_specs=pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
+        in_specs=[row_spec, scale_spec],
+        out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), out_dtype),
         interpret=interpret,
-    )(q2d, scales.astype(jnp.float32).reshape(n_blocks, 1))
+    )(q2d, scales.astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -212,16 +220,13 @@ def dequantize_accumulate_blocks(q2d: jax.Array, scales: jax.Array,
                                  interpret: bool = False):
     n_blocks, block = q2d.shape
     _check_block(q2d.shape)
+    row_spec, scale_spec = _specs(n_blocks, block)
     return pl.pallas_call(
         functools.partial(_dequant_accum_kernel, out_dtype=out_dtype),
         name="dequantize_accumulate_blocks",
         grid=_grid(n_blocks),
-        in_specs=[
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-            _SCALE_SPEC,
-            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
+        in_specs=[row_spec, scale_spec, row_spec],
+        out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), out_dtype),
         interpret=interpret,
-    )(q2d, scales.astype(jnp.float32).reshape(n_blocks, 1), acc)
+    )(q2d, scales.astype(jnp.float32), acc)

@@ -1,8 +1,8 @@
 """Compile-only checks against a described TPU v5e (no chip attached).
 
 The TPU compiler refuses what interpret mode accepts: misaligned block
-shapes, too much VMEM. These tests lower the int8-wire kernels at a real
-bucket size for v5e and assert the compiled program carries the Mosaic
+shapes, too much VMEM. These tests lower the int8-wire kernels at real
+bucket sizes for v5e and assert the compiled program carries the Mosaic
 kernel (``tpu_custom_call``). The topology is described inside a fixture,
 never while a module is imported: only one process may load the TPU
 library, and the suite runs under several workers.
@@ -130,3 +130,28 @@ def test_kernel_keeps_its_name_under_any_caller(one_chip, kernel):
     calls = re.findall(
         r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
     assert [re.sub(r"\.\d+$", "", c) for c in calls] == [kernel], calls
+
+
+# a ragged last tile, the 262M-element embedding bucket, one 8-row tile
+BUCKET_BLOCKS = [12_808, 512_000, 8]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n_blocks", BUCKET_BLOCKS)
+@pytest.mark.parametrize("kernel", list(KERNEL_ARGS))
+def test_kernel_compiles_at_bucket_sizes(one_chip, kernel, n_blocks, dtype):
+    """Mosaic takes the ragged tile and the VMEM footprint of a full one,
+    and the scales need no relayout around the kernel: the compiled program
+    is the custom call alone, with no temporary buffer. `dtype` is the
+    float operand's: the wire buffer quantized, or the accumulator and
+    output dequantized into."""
+    args = [_arg((n_blocks,), jnp.float32, one_chip) if d == "scales"
+            else _arg((n_blocks, BLOCK), dtype if d == jnp.float32 else d,
+                      one_chip)
+            for d in KERNEL_ARGS[kernel]]
+    if kernel == "quantize_ef_blocks":      # the residual stays f32
+        args[1] = _arg((n_blocks, BLOCK), jnp.float32, one_chip)
+    static = {"out_dtype": dtype} if kernel == "dequantize_blocks" else {}
+    compiled = getattr(quant8, kernel).lower(*args, **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

@@ -2,6 +2,7 @@
 swept over shapes and dtypes, plus the fused-vs-composed contracts of the
 single-pass error-feedback hot path (quantize_ef / dequantize_accumulate)."""
 
+import functools
 import inspect
 
 import jax
@@ -204,6 +205,72 @@ def test_dequantize_accumulate_keeps_acc_dtype():
     acc = jax.random.normal(jax.random.PRNGKey(13), (700,))
     out = ops.dequantize_accumulate(q, s, acc, meta)
     assert out.dtype == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# The grid: up to MAX_TILE_ROWS blocks per step, the last tile ragged
+# ---------------------------------------------------------------------------
+
+_TILE = quant8.MAX_TILE_ROWS
+# the padding quantum, 8 x an odd number below one tile, one tile, one tile
+# + 8, several tiles + a ragged edge
+GRID_ROWS = [8, 8 * 13, _TILE, _TILE + 8, 3 * _TILE + 24]
+
+
+def _kernel_args(kernel, dtype, n_blocks):
+    """(args, static kwargs) of one kernel call; `dtype` is the float
+    input's (quantize) or the output's and accumulator's (dequantize)."""
+    kx, kr, ka = jax.random.split(jax.random.PRNGKey(n_blocks), 3)
+    shape = (n_blocks, quant8.DEFAULT_BLOCK)
+    x = (jax.random.normal(kx, shape) * 3).astype(dtype)
+    if kernel == "quantize_blocks":
+        return (x,), {}
+    if kernel == "quantize_ef_blocks":
+        return (x, jax.random.normal(kr, shape) * 0.01), {}
+    q, s = ref.quantize_blocks(x)
+    if kernel == "dequantize_blocks":
+        return (q, s), {"out_dtype": dtype}
+    acc = jax.random.normal(ka, shape).astype(dtype)
+    return (q, s, acc), {"out_dtype": dtype}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.dtype, a.shape, a.view(np.uint8).tobytes()
+
+
+@pytest.mark.parametrize("n_blocks", GRID_ROWS)
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("kernel", ["quantize_blocks", "quantize_ef_blocks",
+                                    "dequantize_blocks",
+                                    "dequantize_accumulate_blocks"])
+def test_reblocked_kernel_bitwise_vs_oracle(kernel, dtype, n_blocks):
+    """Every row of every tile, the ragged last one included, equals the
+    ref.py oracle bit for bit: q, scales, residual and dequantized output.
+    The interpreted kernel body is compiled by XLA, so it is compared with
+    the oracle compiled the same way (eager op-by-op evaluation may round
+    differently, which the older tests above allow for)."""
+    args, kw = _kernel_args(kernel, dtype, n_blocks)
+    got = getattr(quant8, kernel)(*args, interpret=True, **kw)
+    want = jax.jit(functools.partial(getattr(ref, kernel), **kw))(*args)
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert _bits(g) == _bits(w)
+
+
+@pytest.mark.parametrize("n_blocks", [8, 16, 8 * 127, _TILE - 8, _TILE,
+                                      _TILE + 8, 8 * 1543, 12_808, 512_000])
+def test_grid_steps_follow_the_shape(n_blocks):
+    """A tile of up to MAX_TILE_ROWS rows, ceil(n_blocks / tile) grid steps,
+    never more steps than the 8-row grid took and never an empty tile."""
+    tile = quant8.tile_rows(n_blocks)
+    (steps,) = quant8._grid(n_blocks)
+    assert quant8.MAX_TILE_ROWS % 32 == 0       # int8 tiles natively
+    assert tile == min(n_blocks, quant8.MAX_TILE_ROWS)
+    assert steps == -(-n_blocks // tile)
+    assert steps <= n_blocks // quant8.TILE_ROWS
+    assert (steps - 1) * tile < n_blocks
 
 
 # ---------------------------------------------------------------------------
