@@ -26,8 +26,24 @@ class AttnConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN RoPE scaling as DeepSeek-V2's modelling code has it: the rope
+    frequencies ramp from the plain ones (fast dims) to the plain ones over
+    `factor` (slow dims), and attention scales by mscale(factor, mscale_all_dim)
+    squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V2 / MiniCPM3)."""
+    """Multi-head Latent Attention (DeepSeek-V2 / MiniCPM3). q_lora_rank 0:
+    no query low-rank, q = x W_q with no query norm (DeepSeek-V2-Lite)."""
 
     n_heads: int
     q_lora_rank: int
@@ -36,6 +52,7 @@ class MLAConfig:
     qk_rope_dim: int
     v_head_dim: int
     rope_theta: float = 1e4
+    yarn: Optional[YaRNConfig] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +63,27 @@ class MoEConfig:
     capacity_factor: float = 1.25
     dense_residual_ff: int = 0     # Arctic: parallel dense MLP of this width
     router_aux_weight: float = 0.01
+    # DeepSeek-style routing: renormalise the top-k weights only if asked,
+    # then scale them; "switch" aux counts the top-1 expert over the batch,
+    # "seq" is DeepSeek's sequence-wise balance loss over all top-k slots
+    norm_topk_prob: bool = True
+    routed_scaling: float = 1.0
+    aux: str = "switch"            # switch | seq
+    router_fp32: bool = True       # False: router weight in the model dtype
+    shared_ff: int = 0             # shared experts, as one SwiGLU this wide
+    # dropless: every routed (token, k) row is computed, by grouped matmuls
+    # over the held experts; held = (first, count) of the n_experts that
+    # this chip holds (None: all of them)
+    dropless: bool = False
+    held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.held is not None:      # a JSON list becomes a (hashable) pair
+            object.__setattr__(self, "held", tuple(self.held))
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,7 +124,7 @@ class ModelConfig:
     n_layers: int
     d_model: int
     vocab: int
-    block_pattern: Tuple[str, ...]  # cycled over layers: attn|mla|moe|ssm|rglru|local
+    block_pattern: Tuple[str, ...]  # cycled over layers: attn|mla|moe|mla_moe|ssm|rglru|local
     d_ff: int = 0
     mlp_act: str = "silu"
     mlp_gated: bool = True
@@ -110,19 +148,25 @@ class ModelConfig:
     # attention cache of this size for the long_500k decode shape only.
     long_context_window: int = 4096
     source: str = ""               # citation
+    # layers run once ahead of the scanned pattern (DeepSeek's
+    # first_k_dense_replace dense layers); counted in n_layers
+    lead_layers: Tuple[str, ...] = ()
 
     # -- derived -------------------------------------------------------------
 
     @property
     def pattern_repeats(self) -> int:
-        return self.n_layers // len(self.block_pattern)
+        return (self.n_layers - len(self.lead_layers)) // len(self.block_pattern)
 
     @property
     def tail_layers(self) -> Tuple[str, ...]:
-        r = self.n_layers % len(self.block_pattern)
+        r = (self.n_layers - len(self.lead_layers)) % len(self.block_pattern)
         return self.block_pattern[:r]
 
     def layer_kind(self, i: int) -> str:
+        if i < len(self.lead_layers):
+            return self.lead_layers[i]
+        i -= len(self.lead_layers)
         return self.block_pattern[i % len(self.block_pattern)]
 
     @property
@@ -156,14 +200,16 @@ def reduce_for_smoke(cfg: ModelConfig, *, d_model: int = 256,
         kw["attn"] = dataclasses.replace(cfg.attn, n_heads=n_heads, n_kv=n_kv,
                                          head_dim=hd, window=window)
     if cfg.mla is not None:
-        kw["mla"] = dataclasses.replace(cfg.mla, n_heads=4, q_lora_rank=64,
-                                        kv_lora_rank=32, qk_nope_dim=16,
-                                        qk_rope_dim=8, v_head_dim=16)
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, n_heads=4, q_lora_rank=64 if cfg.mla.q_lora_rank else 0,
+            kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
             cfg.moe, n_experts=n_experts, top_k=min(cfg.moe.top_k, 2),
             d_ff=d_ff // 2,
-            dense_residual_ff=(d_ff // 2 if cfg.moe.dense_residual_ff else 0))
+            dense_residual_ff=(d_ff // 2 if cfg.moe.dense_residual_ff else 0),
+            shared_ff=(d_ff // 2 if cfg.moe.shared_ff else 0),
+            held=(0, n_experts // 2) if cfg.moe.held else None)
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=16, head_dim=16,
                                         chunk=16)
@@ -183,6 +229,12 @@ def reduce_for_smoke(cfg: ModelConfig, *, d_model: int = 256,
         long_context_window=64, **kw)
 
 
+def _moe_params(d: int, m: MoEConfig) -> int:
+    held = m.held_range[1]
+    return (held * 3 * d * m.d_ff + d * m.n_experts
+            + 3 * d * (m.dense_residual_ff + m.shared_ff))
+
+
 def param_count_estimate(cfg: ModelConfig) -> float:
     """Rough N for FSDP decisions and 6ND math (exact count comes from defs)."""
     d = cfg.d_model
@@ -193,18 +245,22 @@ def param_count_estimate(cfg: ModelConfig) -> float:
             a = cfg.attn
             n += d * (a.n_heads + 2 * a.n_kv + a.n_heads) * a.head_dim
             n += (3 if cfg.mlp_gated else 2) * d * cfg.d_ff
-        elif k == "mla":
+        elif k in ("mla", "mla_moe"):
             m = cfg.mla
-            n += d * m.q_lora_rank + m.q_lora_rank * m.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
+            qk = m.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
+            n += (d * m.q_lora_rank + m.q_lora_rank * qk if m.q_lora_rank
+                  else d * qk)
             n += d * (m.kv_lora_rank + m.qk_rope_dim)
             n += m.kv_lora_rank * m.n_heads * (m.qk_nope_dim + m.v_head_dim)
             n += m.n_heads * m.v_head_dim * d
-            n += (3 if cfg.mlp_gated else 2) * d * cfg.d_ff
+            if k == "mla":
+                n += (3 if cfg.mlp_gated else 2) * d * cfg.d_ff
+            else:
+                n += _moe_params(d, cfg.moe)
         elif k == "moe":
             a = cfg.attn
             n += d * (a.n_heads + 2 * a.n_kv + a.n_heads) * a.head_dim
-            n += cfg.moe.n_experts * 3 * d * cfg.moe.d_ff + d * cfg.moe.n_experts
-            n += 3 * d * cfg.moe.dense_residual_ff
+            n += _moe_params(d, cfg.moe)
         elif k == "ssm":
             s = cfg.ssm
             d_in = s.expand * d
@@ -227,7 +283,9 @@ def active_param_count_estimate(cfg: ModelConfig) -> float:
     if cfg.moe is None:
         return param_count_estimate(cfg)
     full = param_count_estimate(cfg)
-    moe_layers = sum(1 for i in range(cfg.n_layers) if cfg.layer_kind(i) == "moe")
-    all_experts = moe_layers * cfg.moe.n_experts * 3 * cfg.d_model * cfg.moe.d_ff
+    moe_layers = sum(1 for i in range(cfg.n_layers)
+                     if cfg.layer_kind(i) in ("moe", "mla_moe"))
+    all_experts = (moe_layers * cfg.moe.held_range[1] * 3 * cfg.d_model
+                   * cfg.moe.d_ff)
     active = moe_layers * cfg.moe.top_k * 3 * cfg.d_model * cfg.moe.d_ff
     return float(full - all_experts + active)

@@ -17,6 +17,7 @@ _MODULES = {
     "grok-1-314b": "grok_1_314b",
     "whisper-small": "whisper_small",
     "deepseek-7b": "deepseek_7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(_MODULES)

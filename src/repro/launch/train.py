@@ -301,11 +301,15 @@ def run(cfg: ModelConfig, args: argparse.Namespace) -> RunResult:
                                float(metrics["grad_norm"]))
                 t = time.perf_counter()
                 history.append((s, loss, gnorm, t))
+                moe = "".join(f" {k} {float(v):.6g}"
+                              for k, v in sorted(metrics.items())
+                              if k.startswith("moe/"))
                 if meter is not None:
-                    print(f"{meter.summary()} ({t - t0:.1f}s)", flush=True)
+                    print(f"{meter.summary()}{moe} ({t - t0:.1f}s)",
+                          flush=True)
                 else:
-                    print(f"step {s:5d} loss {loss:.4f} gnorm {gnorm:.3f} "
-                          f"({t - t0:.1f}s)", flush=True)
+                    print(f"step {s:5d} loss {loss:.4f} gnorm {gnorm:.3f}"
+                          f"{moe} ({t - t0:.1f}s)", flush=True)
         if args.trace:
             jax.block_until_ready(state)
             jax.profiler.stop_trace()

@@ -73,6 +73,10 @@ def chunked_sdpa(q, k, v, *, causal: bool = True, window: int | None = None,
     (EXPERIMENTS.md §Perf): a TPU-native reformulation (VMEM-sized KV tiles,
     running max/denominator in f32) of the attention the paper-era stack
     materialized.
+
+    The backward pass recomputes each chunk's scores and probabilities
+    instead of keeping them (the chunk body is checkpointed), so training
+    too holds O(Sq * kv_chunk) of them at a time rather than all Sq * Sk.
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
@@ -110,6 +114,7 @@ def chunked_sdpa(q, k, v, *, causal: bool = True, window: int | None = None,
             + pv
         return (m_new, l_new, acc_new), None
 
+    body = jax.checkpoint(body)
     m0 = jnp.full((B, H, Sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, H, Sq), jnp.float32)
     a0 = jnp.zeros((B, Sq, H, Dv), q.dtype)
@@ -289,10 +294,16 @@ def gqa_decode_cross(p: dict, x1: jax.Array, cross_kv: dict,
 def mla_defs(d_model: int, m: MLAConfig, dtype) -> dict:
     H = m.n_heads
     qk = m.qk_nope_dim + m.qk_rope_dim
+    if m.q_lora_rank:
+        q = {"w_dq": pl.ParamDef((d_model, m.q_lora_rank), pl.K_REPLICATED,
+                                 dtype),
+             "q_norm": pl.ParamDef((m.q_lora_rank,), pl.K_NORM, dtype,
+                                   init="ones"),
+             "w_uq": pl.ParamDef((m.q_lora_rank, H * qk), pl.K_PROJ_IN, dtype)}
+    else:
+        q = {"w_q": pl.ParamDef((d_model, H * qk), pl.K_PROJ_IN, dtype)}
     return {
-        "w_dq": pl.ParamDef((d_model, m.q_lora_rank), pl.K_REPLICATED, dtype),
-        "q_norm": pl.ParamDef((m.q_lora_rank,), pl.K_NORM, dtype, init="ones"),
-        "w_uq": pl.ParamDef((m.q_lora_rank, H * qk), pl.K_PROJ_IN, dtype),
+        **q,
         "w_dkv": pl.ParamDef((d_model, m.kv_lora_rank + m.qk_rope_dim),
                              pl.K_REPLICATED, dtype),
         "kv_norm": pl.ParamDef((m.kv_lora_rank,), pl.K_NORM, dtype, init="ones"),
@@ -304,21 +315,41 @@ def mla_defs(d_model: int, m: MLAConfig, dtype) -> dict:
     }
 
 
+def _mla_q(p, x, m: MLAConfig):
+    """Queries (..., H * (nope + rope)): through the low-rank and its norm,
+    or x W_q where the config has no query low-rank."""
+    if m.q_lora_rank:
+        return common.rmsnorm(x @ p["w_dq"], p["q_norm"]) @ p["w_uq"]
+    return x @ p["w_q"]
+
+
+def _mla_scale(m: MLAConfig) -> float:
+    """Attention's softmax scale: qk**-0.5, times YaRN's mscale squared."""
+    if m.yarn is None:
+        return 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    return common.yarn_softmax_scale(m.qk_nope_dim + m.qk_rope_dim, m.yarn)
+
+
+def _mla_scores(scores, m: MLAConfig):
+    if m.yarn is None:
+        return scores / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    return scores * _mla_scale(m)
+
+
 def _mla_qkv(p, x, m: MLAConfig, pos0: int):
     """Shared q / latent computation for a full sequence."""
     B, S, _ = x.shape
     H = m.n_heads
-    cq = common.rmsnorm(x @ p["w_dq"], p["q_norm"])
-    q = (cq @ p["w_uq"]).reshape(B, S, H, m.qk_nope_dim + m.qk_rope_dim)
+    q = _mla_q(p, x, m).reshape(B, S, H, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_pe = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim:]
     ckv_full = x @ p["w_dkv"]
     ckv, kpe = (ckv_full[..., : m.kv_lora_rank],
                 ckv_full[..., m.kv_lora_rank:])
     ckv = common.rmsnorm(ckv, p["kv_norm"])
     positions = jnp.arange(S) + pos0
-    q_pe = common.apply_rope(q_pe, positions, theta=m.rope_theta)
+    q_pe = common.apply_rope(q_pe, positions, theta=m.rope_theta, yarn=m.yarn)
     kpe = common.apply_rope(kpe[..., None, :], positions,
-                            theta=m.rope_theta)[..., 0, :]
+                            theta=m.rope_theta, yarn=m.yarn)[..., 0, :]
     return q_nope, q_pe, ckv, kpe
 
 
@@ -339,11 +370,11 @@ def mla_apply(p: dict, x: jax.Array, m: MLAConfig, *, pos0: int = 0,
                                       (B, S, H, m.qk_rope_dim))], axis=-1)
         o = chunked_sdpa(q_cat, k_cat, v, causal=True, window=window,
                          q_offset=pos0, kv_chunk=kv_chunk,
-                         scale=1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim))
+                         scale=_mla_scale(m))
         return o.reshape(B, S, H * m.v_head_dim) @ p["wo"]
     scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
               + jnp.einsum("bqhd,bkd->bhqk", q_pe, kpe)).astype(jnp.float32)
-    scores = scores / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scores = _mla_scores(scores, m)
     mask = common.causal_mask(S, S, window=window)
     scores = jnp.where(mask[None, None], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
@@ -375,15 +406,14 @@ def mla_decode(p: dict, x1: jax.Array, cache: dict, pos: jax.Array,
     B = x1.shape[0]
     H = m.n_heads
     slots = cache["ckv"].shape[1]
-    cq = common.rmsnorm(x1 @ p["w_dq"], p["q_norm"])
-    q = (cq @ p["w_uq"]).reshape(B, 1, H, m.qk_nope_dim + m.qk_rope_dim)
+    q = _mla_q(p, x1, m).reshape(B, 1, H, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_pe = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim:]
     posv = jnp.full((1,), pos)
-    q_pe = common.apply_rope(q_pe, posv, theta=m.rope_theta)
+    q_pe = common.apply_rope(q_pe, posv, theta=m.rope_theta, yarn=m.yarn)
     ckv1_full = x1 @ p["w_dkv"]
     ckv1 = common.rmsnorm(ckv1_full[..., : m.kv_lora_rank], p["kv_norm"])
     kpe1 = common.apply_rope(ckv1_full[..., None, m.kv_lora_rank:], posv,
-                             theta=m.rope_theta)[..., 0, :]
+                             theta=m.rope_theta, yarn=m.yarn)[..., 0, :]
     write = pos % slots if window else pos
     ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv1, write, axis=1)
     kpe = jax.lax.dynamic_update_slice_in_dim(cache["kpe"], kpe1, write, axis=1)
@@ -392,7 +422,7 @@ def mla_decode(p: dict, x1: jax.Array, cache: dict, pos: jax.Array,
     q_abs = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)
     scores = (jnp.einsum("bqhr,bkr->bhqk", q_abs, ckv)
               + jnp.einsum("bqhd,bkd->bhqk", q_pe, kpe)).astype(jnp.float32)
-    scores = scores / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scores = _mla_scores(scores, m)
     idx = jnp.arange(slots)
     if window:
         valid = jnp.where(pos >= slots, jnp.ones_like(idx, dtype=bool),

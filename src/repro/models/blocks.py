@@ -5,6 +5,7 @@ Block kinds (cycled through ModelConfig.block_pattern):
   local  -- sliding-window self-attention + dense MLP (hybrid models)
   mla    -- multi-head latent attention + dense MLP
   moe    -- self-attention + mixture-of-experts MLP (+ optional dense residual)
+  mla_moe -- multi-head latent attention + mixture-of-experts MLP (DeepSeek-V2)
   ssm    -- Mamba-2 SSD mixer (no separate MLP, as in the source arch)
   rglru  -- RG-LRU recurrent mixer + dense MLP
   enc    -- bidirectional self-attention + MLP (encoder towers)
@@ -60,6 +61,11 @@ def block_defs(kind: str, cfg: ModelConfig) -> dict:
                 "attn": attn_mod.gqa_defs(d, cfg.attn, dt),
                 "ln2": norm_defs(d, cfg),
                 "moe": moe.moe_defs(d, cfg.moe, dt)}
+    if kind == "mla_moe":
+        return {"ln1": norm_defs(d, cfg),
+                "mla": attn_mod.mla_defs(d, cfg.mla, dt),
+                "ln2": norm_defs(d, cfg),
+                "moe": moe.moe_defs(d, cfg.moe, dt)}
     if kind == "ssm":
         return {"ln1": norm_defs(d, cfg),
                 "ssm": ssm.ssm_defs(d, cfg.ssm, dt)}
@@ -92,6 +98,7 @@ class BlockCtx:
     batch_axes: tuple = ("data",)
     fsdp_axes: tuple = ()
     wgather_wire: str = "bf16"      # int8: quantized ZeRO weight gathers
+    gmm_backend: str = "ragged"     # dropless MoE: kernels/gmm.py backend
     # hybrid execution: activation-exchange axis for tensor-parallel blocks.
     # Whether a given block actually runs sharded is detected from its shard
     # shapes (attn_tp / mlp_tp) — the per-layer hybrid plan leaves fallback
@@ -123,10 +130,27 @@ class BlockCtx:
 
 # --- train / full-sequence apply ----------------------------------------------
 
-def block_apply(kind: str, p: dict, h: jax.Array, ctx: BlockCtx):
-    """Returns (h, aux_loss)."""
+def _moe_ffn(p: dict, x: jax.Array, ctx: BlockCtx):
+    """The MoE feed-forward of a block: (y, stats)."""
     cfg = ctx.cfg
-    aux = jnp.zeros((), jnp.float32)
+    if cfg.moe.dropless:
+        return moe.moe_dropless(p, x, cfg.moe, act=cfg.mlp_act,
+                                gmm_backend=ctx.gmm_backend)
+    if ctx.moe_impl == "ep":
+        y, aux = moe.moe_apply_ep(p, x, cfg.moe, act=cfg.mlp_act,
+                                  mesh=ctx.mesh, batch_axes=ctx.batch_axes,
+                                  fsdp_axes=ctx.fsdp_axes,
+                                  wgather_wire=ctx.wgather_wire)
+    else:
+        y, aux = moe.moe_apply(p, x, cfg.moe, act=cfg.mlp_act)
+    return y, {"aux": aux}
+
+
+def block_apply(kind: str, p: dict, h: jax.Array, ctx: BlockCtx):
+    """Returns (h, stats): stats {"aux": aux_loss} (moe.zero_stats for the
+    keys of a dropless MoE block)."""
+    cfg = ctx.cfg
+    stats = {"aux": jnp.zeros((), jnp.float32)}
     if kind in ("attn", "local", "moe", "enc"):
         w = ctx.window_for(kind)
         x = norm_apply(p["ln1"], h, cfg)
@@ -137,37 +161,36 @@ def block_apply(kind: str, p: dict, h: jax.Array, ctx: BlockCtx):
                                    tp_axis=ctx.attn_tp(p["attn"], a))
         x = norm_apply(p["ln2"], h, cfg)
         if kind == "moe":
-            if ctx.moe_impl == "ep":
-                y, aux = moe.moe_apply_ep(p["moe"], x, cfg.moe, act=cfg.mlp_act,
-                                          mesh=ctx.mesh,
-                                          batch_axes=ctx.batch_axes,
-                                          fsdp_axes=ctx.fsdp_axes,
-                                          wgather_wire=ctx.wgather_wire)
-            else:
-                y, aux = moe.moe_apply(p["moe"], x, cfg.moe, act=cfg.mlp_act)
+            y, stats = _moe_ffn(p["moe"], x, ctx)
         else:
             y = mlp.mlp_apply(p["mlp"], x, act=cfg.mlp_act, gated=cfg.mlp_gated,
                               tp_axis=ctx.mlp_tp(p["mlp"]))
-        return h + y, aux
-    if kind == "mla":
+        return h + y, stats
+    if kind in ("mla", "mla_moe"):
         x = norm_apply(p["ln1"], h, cfg)
-        h = h + attn_mod.mla_apply(p["mla"], x, cfg.mla,
-                                   window=ctx.window_override,
-                                   kv_chunk=ctx.kv_chunk)
+        with jax.named_scope("mla"):
+            h = h + attn_mod.mla_apply(p["mla"], x, cfg.mla,
+                                       window=ctx.window_override,
+                                       kv_chunk=ctx.kv_chunk)
         x = norm_apply(p["ln2"], h, cfg)
-        return h + mlp.mlp_apply(p["mlp"], x, act=cfg.mlp_act,
-                                 gated=cfg.mlp_gated,
-                                 tp_axis=ctx.mlp_tp(p["mlp"])), aux
+        if kind == "mla_moe":
+            y, stats = _moe_ffn(p["moe"], x, ctx)
+            return h + y, stats
+        with jax.named_scope("mlp"):
+            y = mlp.mlp_apply(p["mlp"], x, act=cfg.mlp_act,
+                              gated=cfg.mlp_gated,
+                              tp_axis=ctx.mlp_tp(p["mlp"]))
+        return h + y, stats
     if kind == "ssm":
         x = norm_apply(p["ln1"], h, cfg)
-        return h + ssm.ssm_apply(p["ssm"], x, cfg.ssm), aux
+        return h + ssm.ssm_apply(p["ssm"], x, cfg.ssm), stats
     if kind == "rglru":
         x = norm_apply(p["ln1"], h, cfg)
         h = h + rglru.rglru_apply(p["rec"], x, cfg.rglru)
         x = norm_apply(p["ln2"], h, cfg)
         return h + mlp.mlp_apply(p["mlp"], x, act=cfg.mlp_act,
                                  gated=cfg.mlp_gated,
-                                 tp_axis=ctx.mlp_tp(p["mlp"])), aux
+                                 tp_axis=ctx.mlp_tp(p["mlp"])), stats
     if kind == "cross":
         x = norm_apply(p["ln1"], h, cfg)
         h = h + attn_mod.gqa_apply(p["attn"], x, cfg.attn,
@@ -178,7 +201,7 @@ def block_apply(kind: str, p: dict, h: jax.Array, ctx: BlockCtx):
                                    mask=None)
         x = norm_apply(p["ln2"], h, cfg)
         return h + mlp.mlp_apply(p["mlp"], x, act=cfg.mlp_act,
-                                 gated=cfg.mlp_gated), aux
+                                 gated=cfg.mlp_gated), stats
     raise ValueError(kind)
 
 
@@ -191,7 +214,7 @@ def block_init_cache(kind: str, cfg: ModelConfig, batch: int, max_seq: int,
         return attn_mod.gqa_init_cache(batch, max_seq, cfg.attn, dt,
                                        window=ctx.window_for(kind),
                                        kv_dtype=ctx.kv_dtype)
-    if kind == "mla":
+    if kind in ("mla", "mla_moe"):
         return attn_mod.mla_init_cache(batch, max_seq, cfg.mla, dt,
                                        window=ctx.window_override)
     if kind == "ssm":
@@ -216,7 +239,7 @@ def block_prefill_cache(kind: str, p: dict, h_in: jax.Array, cfg: ModelConfig,
         return attn_mod.gqa_prefill_cache(p["attn"], x, cfg.attn,
                                           window=ctx.window_for(kind),
                                           kv_dtype=ctx.kv_dtype)
-    if kind == "mla":
+    if kind in ("mla", "mla_moe"):
         x = norm_apply(p["ln1"], h_in, cfg)
         return attn_mod.mla_prefill_cache(p["mla"], x, cfg.mla,
                                           window=ctx.window_override)
@@ -246,16 +269,21 @@ def block_decode(kind: str, p: dict, h1: jax.Array, cache, pos, ctx: BlockCtx):
         h1 = h1 + y
         x = norm_apply(p["ln2"], h1, cfg)
         if kind == "moe":
-            y, _ = moe.moe_apply(p["moe"], x, cfg.moe, act=cfg.mlp_act)
+            y, _ = _moe_ffn(p["moe"], x, dataclasses.replace(ctx,
+                                                             moe_impl="gather"))
         else:
             y = mlp.mlp_apply(p["mlp"], x, act=cfg.mlp_act, gated=cfg.mlp_gated)
         return h1 + y, cache2
-    if kind == "mla":
+    if kind in ("mla", "mla_moe"):
         x = norm_apply(p["ln1"], h1, cfg)
         y, cache2 = attn_mod.mla_decode(p["mla"], x, cache, pos, cfg.mla,
                                         window=ctx.window_override)
         h1 = h1 + y
         x = norm_apply(p["ln2"], h1, cfg)
+        if kind == "mla_moe":
+            y, _ = _moe_ffn(p["moe"], x, dataclasses.replace(
+                ctx, moe_impl="gather"))
+            return h1 + y, cache2
         return h1 + mlp.mlp_apply(p["mlp"], x, act=cfg.mlp_act,
                                   gated=cfg.mlp_gated), cache2
     if kind == "ssm":

@@ -7,6 +7,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.planner import ParamDef
 
@@ -86,22 +87,74 @@ def rope_freqs(head_dim: int, rotary_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** exponents)          # (rotary_dim/2,)
 
 
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 mscale ln(scale) + 1 (1 if the
+    context is not stretched)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(dim: int, base: float, yarn) -> tuple:
+    """(low, high): the rotary pairs between which the frequencies ramp
+    from interpolated to plain, from the rotations each pair makes over
+    the original context (beta_fast and beta_slow of them)."""
+    def corr(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+    low = math.floor(corr(yarn.beta_fast))
+    high = math.ceil(corr(yarn.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_freqs(dim: int, base: float, yarn) -> jax.Array:
+    """YaRN inverse frequencies (dim/2,) as DeepSeek-V2's modelling code
+    computes them: plain below `low`, divided by `factor` above `high`, a
+    linear ramp between."""
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    inter = extra / np.float32(yarn.factor)
+    low, high = yarn_correction_range(dim, base, yarn)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    mask = (1.0 - ramp).astype(np.float32)
+    return jnp.asarray(inter * (1 - mask) + extra * mask)
+
+
+def yarn_softmax_scale(qk_dim: int, yarn) -> float:
+    """Attention's softmax scale under YaRN: qk_dim**-0.5 times
+    mscale(factor, mscale_all_dim) squared."""
+    scale = qk_dim ** -0.5
+    if yarn is not None and yarn.mscale_all_dim:
+        m = yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        scale = scale * m * m
+    return scale
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, *, rotary_frac: float = 1.0,
-               theta: float = 1e4) -> jax.Array:
+               theta: float = 1e4, yarn=None) -> jax.Array:
     """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
 
     rotary_frac < 1 rotates only the leading fraction of head_dim (partial
     rotary, e.g. ChatGLM's 2D-RoPE halves and GLM/NeoX-style models).
+    yarn (configs.base.YaRNConfig): YaRN frequencies, and cos / sin scaled
+    by mscale(factor, mscale) / mscale(factor, mscale_all_dim).
     """
     head_dim = x.shape[-1]
     rot = int(head_dim * rotary_frac)
     rot -= rot % 2
     if rot == 0:
         return x
-    inv = rope_freqs(head_dim, rot, theta)                 # (rot/2,)
+    inv = (rope_freqs(head_dim, rot, theta) if yarn is None
+           else yarn_freqs(rot, theta, yarn))              # (rot/2,)
     ang = positions[..., None].astype(jnp.float32) * inv   # (..., seq, rot/2)
     cos = jnp.cos(ang)[..., None, :]                        # (..., seq, 1, rot/2)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     xr = x[..., :rot].astype(jnp.float32)
     x1, x2 = xr[..., : rot // 2], xr[..., rot // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)

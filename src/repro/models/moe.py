@@ -1,7 +1,18 @@
 """Mixture-of-Experts layers: top-k token-choice routing.
 
-Two dispatch implementations, both capacity-based (GShard semantics, overflow
-tokens dropped from the expert path but preserved by the residual):
+Three dispatch implementations. `moe_dropless` computes every routed row:
+
+  * `moe_dropless` (DeepSeek-style, dropless): the (token, k) assignments
+    that land on the experts this chip holds (MoEConfig.held) are sorted by
+    expert and run through grouped matmuls (kernels/gmm.py) over the real
+    group sizes, then gate-weighted and summed back per token, plus the
+    shared experts. The router stays n_experts wide; buffers are static at
+    the worst case of T * top_k rows, the grouped matmuls' work follows the
+    routed rows. Its scopes name the stages: moe/route, moe/dispatch,
+    moe/experts, moe/combine, moe/shared.
+
+The other two are capacity-based (GShard semantics, overflow tokens dropped
+from the expert path but preserved by the residual):
 
   * `moe_apply` (baseline, pure GSPMD): sort-based dispatch with static
     shapes — argsort tokens by expert, scatter into an (E, C, d) buffer,
@@ -31,20 +42,45 @@ import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
 from repro.core import planner as pl
+from repro.kernels import gmm as gmm_lib
 from repro.models import common, mlp
 
 
 def moe_defs(d_model: int, m: MoEConfig, dtype) -> dict:
+    if m.held is not None and not m.dropless:
+        raise ValueError("MoEConfig.held needs the dropless path")
+    e = m.held_range[1]
     d = {
         "router": pl.ParamDef((d_model, m.n_experts), pl.K_REPLICATED,
-                              jnp.float32),
-        "w1": pl.ParamDef((m.n_experts, d_model, m.d_ff), pl.K_EXPERT_IN, dtype),
-        "w2": pl.ParamDef((m.n_experts, m.d_ff, d_model), pl.K_EXPERT_OUT, dtype),
-        "w3": pl.ParamDef((m.n_experts, d_model, m.d_ff), pl.K_EXPERT_IN, dtype),
+                              jnp.float32 if m.router_fp32 else dtype),
+        "w1": pl.ParamDef((e, d_model, m.d_ff), pl.K_EXPERT_IN, dtype),
+        "w2": pl.ParamDef((e, m.d_ff, d_model), pl.K_EXPERT_OUT, dtype),
+        "w3": pl.ParamDef((e, d_model, m.d_ff), pl.K_EXPERT_IN, dtype),
     }
     if m.dense_residual_ff:
         d["dense"] = mlp.mlp_defs(d_model, m.dense_residual_ff, dtype)
+    if m.shared_ff:
+        d["shared"] = mlp.mlp_defs(d_model, m.shared_ff, dtype)
     return d
+
+
+def zero_stats(m: MoEConfig | None) -> dict:
+    """The per-layer statistics a block reports, at zero: the aux loss,
+    and on the dropless path the rows routed to held experts and the
+    largest held group."""
+    z = jnp.zeros((), jnp.float32)
+    if m is not None and m.dropless:
+        return {"aux": z, "held_rows": z, "max_expert_rows": z}
+    return {"aux": z}
+
+
+def merge_stats(total: dict, layer: dict) -> dict:
+    """Running statistics over layers: sums, and maxima for max_*."""
+    out = dict(total)
+    for k, v in layer.items():
+        out[k] = jnp.maximum(out[k], v) if k.startswith("max_") \
+            else out[k] + v
+    return out
 
 
 def capacity(n_tokens: int, m: MoEConfig) -> int:
@@ -52,13 +88,23 @@ def capacity(n_tokens: int, m: MoEConfig) -> int:
     return max(8, ((c + 7) // 8) * 8)     # sublane-aligned
 
 
-def route(xf: jax.Array, router_w: jax.Array, m: MoEConfig):
-    """xf (T, d) -> (weights (T, k), ids (T, k), aux_loss scalar)."""
-    logits = (xf.astype(jnp.float32) @ router_w).astype(jnp.float32)
+def route(xf: jax.Array, router_w: jax.Array, m: MoEConfig, *,
+          batch: int = 1):
+    """xf (T, d), the rows of `batch` sequences -> (weights (T, k), ids
+    (T, k), aux_loss scalar). Logits in float32 whatever the router's
+    dtype; softmax scores, greedy top-k, renormalised if
+    m.norm_topk_prob, times m.routed_scaling."""
+    logits = (xf.astype(jnp.float32) @ router_w.astype(jnp.float32)
+              ).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     weights, ids = jax.lax.top_k(probs, m.top_k)
-    weights = weights / jnp.maximum(jnp.sum(weights, axis=-1, keepdims=True),
-                                    1e-9)
+    if m.norm_topk_prob:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
+    if m.routed_scaling != 1.0:
+        weights = weights * m.routed_scaling
+    if m.aux == "seq":
+        return weights, ids, seq_aux(probs, ids, m, batch)
     # load-balance auxiliary loss (Switch/GShard): E * sum_e f_e * p_e
     T = xf.shape[0]
     me = jnp.mean(probs, axis=0)
@@ -66,6 +112,100 @@ def route(xf: jax.Array, router_w: jax.Array, m: MoEConfig):
     ce = jnp.sum(one_hot, axis=0) / T
     aux = m.n_experts * jnp.sum(me * ce)
     return weights, ids, aux
+
+
+def seq_aux(probs, ids, m: MoEConfig, batch: int):
+    """DeepSeek's sequence-wise balance loss (unweighted): per sequence b,
+    ce[b, e] = its top-k slots on expert e / (S k / E), and the loss is
+    mean_b sum_e ce[b, e] * mean_t probs[b, t, e]."""
+    E, k = m.n_experts, m.top_k
+    S = probs.shape[0] // batch
+    slots = jnp.sum(jax.nn.one_hot(ids, E, dtype=jnp.float32), axis=1)
+    ce = jnp.sum(slots.reshape(batch, S, E), axis=1) / (S * k / E)
+    p_mean = jnp.mean(probs.reshape(batch, S, E), axis=1)
+    return jnp.mean(jnp.sum(ce * p_mean, axis=-1))
+
+
+@jax.custom_vjp
+def _dispatch(xf, token, inv):
+    """Rows xf[token] (token: the row of each sorted assignment); the way
+    back gathers each assignment's cotangent through the inverse
+    permutation `inv` and sums a token's k of them (no scatter)."""
+    return xf[token]
+
+
+def _dispatch_fwd(xf, token, inv):
+    return xf[token], (inv, xf.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    inv, T = res
+    k = g.shape[0] // T
+    dx = jnp.sum(g[inv].reshape(T, k, -1).astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(y, idx, inv):
+    """y[idx] for a permutation idx whose inverse is inv: its transpose is
+    the gather y[inv], not a scatter."""
+    return y[idx]
+
+
+def _permute_fwd(y, idx, inv):
+    return y[idx], (idx, inv)
+
+
+def _permute_bwd(res, g):
+    return g[res[1]], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def moe_dropless(p: dict, x: jax.Array, m: MoEConfig, *, act: str = "silu",
+                 gmm_backend: str = "ragged"):
+    """Dropless MoE over the held experts. x (B, S, d) -> (y, stats):
+    stats = {aux, held_rows, max_expert_rows} of this layer. gmm_backend:
+    kernels/gmm.py's (the train step picks the compiled kernels on a TPU:
+    trainer.make_train_step)."""
+    B, S, d = x.shape
+    T, K = B * S, m.top_k
+    first, count = m.held_range
+    spec = gmm_lib.Spec(backend=gmm_backend)
+    xf = x.reshape(T, d)
+    with jax.named_scope("moe/route"):
+        weights, ids, aux = route(xf, p["router"], m, batch=B)
+    with jax.named_scope("moe/dispatch"):
+        local = ids - first
+        held = (local >= 0) & (local < count)               # (T, K)
+        key = jnp.where(held, local, count).reshape(-1)     # (T*K,)
+        # sorts and a dense count, no scatter: their cost is the same for
+        # any routing
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=key.dtype),
+                        axis=0, dtype=jnp.int32)
+        xs = _dispatch(xf, order // K, inv)                 # (T*K, d)
+    with jax.named_scope("moe/experts"):
+        f = common.act_fn(act)
+        h = f(gmm_lib.grouped_matmul(xs, p["w1"], sizes, spec)) \
+            * gmm_lib.grouped_matmul(xs, p["w3"], sizes, spec)
+        ys = gmm_lib.grouped_matmul(h, p["w2"], sizes, spec)  # pad rows 0
+    with jax.named_scope("moe/combine"):
+        yf = _permute(ys, inv, order).reshape(T, K, d)
+        gate = jnp.where(held, weights, 0.0)
+        y = jnp.sum(yf.astype(jnp.float32) * gate[..., None], axis=1)
+        y = y.astype(x.dtype).reshape(B, S, d)
+    if "shared" in p:
+        with jax.named_scope("moe/shared"):
+            y = y + mlp.mlp_apply(p["shared"], x, act=act)
+    stats = {"aux": aux, "held_rows": jnp.sum(sizes).astype(jnp.float32),
+             "max_expert_rows": jnp.max(sizes).astype(jnp.float32)}
+    return y, stats
 
 
 def _expert_ffn(w1, w2, w3, xe, act: str):

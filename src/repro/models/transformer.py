@@ -4,8 +4,10 @@ train / prefill / decode entry points for every assigned architecture.
 Layer stacking: the repeating block pattern is scanned (`lax.scan`) over
 `pattern_repeats` with parameters stacked on a leading dim — this keeps the
 HLO small enough to compile 480B-parameter configs against a 512-device mesh
-in seconds (see DESIGN.md §6). A non-divisible remainder ("tail") is
-unrolled. Smoke tests run the same code with 1-2 repeats on CPU.
+in seconds (see DESIGN.md §6). Leading layers of their own kind
+(`lead_layers`, DeepSeek's dense first layer) run once before the scan, a
+non-divisible remainder ("tail") after it; both are unrolled. Smoke tests
+run the same code with 1-2 repeats on CPU.
 
 Modality frontends are stubs per the assignment: VLMs consume precomputed
 patch embeddings (projected into d_model), audio models consume precomputed
@@ -23,7 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import planner as pl
-from repro.models import blocks, common
+from repro.models import blocks, common, moe
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +81,11 @@ class Model:
                 enc["in_proj"] = pl.ParamDef((cfg.encoder.d_input, d),
                                              pl.K_REPLICATED, cfg.dtype)
             defs["encoder"] = enc
+        if cfg.lead_layers:
+            defs["lead"] = {
+                f"l{i}_{kind}": blocks.block_defs(kind, cfg)
+                for i, kind in enumerate(cfg.lead_layers)
+            }
         reps = cfg.pattern_repeats
         if reps > 0:
             defs["blocks"] = {
@@ -113,13 +120,15 @@ class Model:
     def _ctx(self, enc_out=None, window_override=None, moe_impl="gather",
              kv_chunk=None, kv_dtype="native", mesh=None,
              batch_axes=("data",), fsdp_axes=(),
-             wgather_wire="bf16", tp_axis=None) -> blocks.BlockCtx:
+             wgather_wire="bf16", tp_axis=None,
+             gmm_backend="ragged") -> blocks.BlockCtx:
         return blocks.BlockCtx(cfg=self.cfg, window_override=window_override,
                                enc_out=enc_out, moe_impl=moe_impl,
                                kv_chunk=kv_chunk, kv_dtype=kv_dtype,
                                mesh=mesh, batch_axes=batch_axes,
                                fsdp_axes=fsdp_axes,
-                               wgather_wire=wgather_wire, tp_axis=tp_axis)
+                               wgather_wire=wgather_wire, tp_axis=tp_axis,
+                               gmm_backend=gmm_backend)
 
     def _embed(self, params: dict, batch: Batch, *, pos0: int = 0) -> jax.Array:
         cfg = self.cfg
@@ -152,30 +161,39 @@ class Model:
         return blocks.norm_apply(p["ln_f"], h, cfg)
 
     def _run_blocks(self, params: dict, h: jax.Array, ctx: blocks.BlockCtx):
-        """Scan the pattern repeats, then the tail. Returns (h, aux_total)."""
+        """The lead layers, the scanned pattern repeats, then the tail.
+        Returns (h, stats summed over layers: moe.merge_stats)."""
         cfg = self.cfg
-        aux0 = jnp.zeros((), jnp.float32)
+        stats0 = moe.zero_stats(cfg.moe)
+
+        for i, kind in enumerate(cfg.lead_layers):
+            def lead(ps, hh, kind=kind):
+                return blocks.block_apply(kind, ps, hh, ctx)
+            if cfg.remat:
+                lead = jax.checkpoint(lead)
+            h, st = lead(params["lead"][f"l{i}_{kind}"], h)
+            stats0 = moe.merge_stats(stats0, st)
 
         if cfg.pattern_repeats > 0:
             stacked = tuple(params["blocks"][f"p{i}_{k}"]
                             for i, k in enumerate(cfg.block_pattern))
 
             def body(carry, pslices):
-                hh, aux = carry
+                hh, stats = carry
                 for kind, ps in zip(cfg.block_pattern, pslices):
-                    hh, a = blocks.block_apply(kind, ps, hh, ctx)
-                    aux = aux + a
-                return (hh, aux), None
+                    hh, st = blocks.block_apply(kind, ps, hh, ctx)
+                    stats = moe.merge_stats(stats, st)
+                return (hh, stats), None
 
             if cfg.remat:
                 body = jax.checkpoint(body)
-            (h, aux0), _ = jax.lax.scan(body, (h, aux0), stacked)
+            (h, stats0), _ = jax.lax.scan(body, (h, stats0), stacked)
 
         for i, kind in enumerate(cfg.tail_layers):
-            h, a = blocks.block_apply(kind, params["tail"][f"t{i}_{kind}"], h,
-                                      ctx)
-            aux0 = aux0 + a
-        return h, aux0
+            h, st = blocks.block_apply(kind, params["tail"][f"t{i}_{kind}"],
+                                       h, ctx)
+            stats0 = moe.merge_stats(stats0, st)
+        return h, stats0
 
     def _head(self, params: dict, h: jax.Array) -> jax.Array:
         cfg = self.cfg
@@ -196,10 +214,16 @@ class Model:
             enc_out = self._encode(params, batch.frame_embeds)
         ctx = self._ctx(enc_out=enc_out, **ctx_kw)
         h = self._embed(params, batch)
-        h, self._last_aux = self._run_blocks(params, h, ctx)
+        h, self._last_stats = self._run_blocks(params, h, ctx)
         return self._head(params, h)
 
-    def loss(self, params: dict, batch: Batch, **ctx_kw) -> jax.Array:
+    def loss(self, params: dict, batch: Batch, *, with_stats: bool = False,
+             **ctx_kw):
+        """Mean next-token cross-entropy, plus the weighted MoE aux loss.
+        with_stats=True returns (loss, stats): for an MoE model
+        {"moe/aux": the aux term added}, and on the dropless path
+        "moe/held_rows" (assignments on held experts, over the layers) and
+        "moe/max_expert_rows" (the largest held group of any layer)."""
         logits = self.forward(params, batch, **ctx_kw)
         cfg = self.cfg
         if cfg.vlm_img_tokens and batch.img_embeds is not None:
@@ -207,9 +231,14 @@ class Model:
         loss = common.softmax_xent(logits[:, :-1], batch.labels[:, 1:],
                                    None if batch.mask is None
                                    else batch.mask[:, 1:])
+        stats = {}
         if self.cfg.moe is not None:
-            loss = loss + self.cfg.moe.router_aux_weight * self._last_aux
-        return loss
+            aux = self.cfg.moe.router_aux_weight * self._last_stats["aux"]
+            loss = loss + aux
+            stats = {f"moe/{k}": v for k, v in self._last_stats.items()
+                     if k != "aux"}
+            stats["moe/aux"] = aux
+        return (loss, stats) if with_stats else loss
 
     # ---------------- serving ----------------
 
@@ -217,6 +246,12 @@ class Model:
         cfg = self.cfg
         ctx = self._ctx(**ctx_kw)
         cache: dict = {}
+        if cfg.lead_layers:
+            cache["lead"] = {
+                f"l{i}_{kind}": blocks.block_init_cache(kind, cfg, batch,
+                                                        max_seq, ctx)
+                for i, kind in enumerate(cfg.lead_layers)
+            }
         if cfg.pattern_repeats > 0:
             cache["blocks"] = {}
             for i, kind in enumerate(cfg.block_pattern):
@@ -255,8 +290,8 @@ class Model:
                     pad[1] = (0, max(0, max_seq - S))
                     return jnp.pad(x, pad)
                 return x
-            if kind in ("attn", "local", "moe", "mla", "cross"):
-                w = (ctx.window_for(kind) if kind != "mla"
+            if kind in ("attn", "local", "moe", "mla", "mla_moe", "cross"):
+                w = (ctx.window_for(kind) if kind not in ("mla", "mla_moe")
                      else ctx.window_override)
                 if kind == "cross":
                     return {"self": jax.tree.map(grow, c["self"]),
@@ -265,6 +300,13 @@ class Model:
                     return jax.tree.map(grow, c)
             return c
 
+        if cfg.lead_layers:
+            cache["lead"] = {}
+            for i, kind in enumerate(cfg.lead_layers):
+                ps = params["lead"][f"l{i}_{kind}"]
+                c = blocks.block_prefill_cache(kind, ps, h, cfg, ctx)
+                cache["lead"][f"l{i}_{kind}"] = pad_cache(kind, c)
+                h, _ = blocks.block_apply(kind, ps, h, ctx)
         if cfg.pattern_repeats > 0:
             stacked = tuple(params["blocks"][f"p{i}_{k}"]
                             for i, k in enumerate(cfg.block_pattern))
@@ -306,6 +348,12 @@ class Model:
             h = h + jax.lax.dynamic_slice_in_dim(
                 params["pos_emb"], pos, 1, axis=0)[None]
         new_cache: dict = {"blocks": {}, "tail": {}}
+        if cfg.lead_layers:
+            new_cache["lead"] = {}
+            for i, kind in enumerate(cfg.lead_layers):
+                key = f"l{i}_{kind}"
+                h, new_cache["lead"][key] = blocks.block_decode(
+                    kind, params["lead"][key], h, cache["lead"][key], pos, ctx)
 
         if cfg.pattern_repeats > 0:
             stacked_p = tuple(params["blocks"][f"p{i}_{k}"]

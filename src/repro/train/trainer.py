@@ -39,6 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import scheduler
 from repro.core.engine import CommConfig, CommEngine
 from repro.core.planner import Planner
+from repro.kernels import gmm as gmm_lib
 from repro.models.transformer import Batch, Model
 from repro.optim import optimizers as opt_lib
 
@@ -226,13 +227,22 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         # place the f/g activation collectives; DP-fallback layers see
         # full-size (replicated) weights and ignore the axis
         loss_kw["tp_axis"] = tp_axis
+    if cfg.moe is not None and cfg.moe.dropless:
+        # the grouped-matmul kernels for the platform the step runs on
+        loss_kw["gmm_backend"] = gmm_lib.backend_for(
+            mesh.devices.flat[0].platform)
 
-    def loss_fn(params, batch: Batch):
+    def loss_stats_fn(params, batch: Batch):
+        """(loss, stats): the model's statistics (MoE counters) beside the
+        loss, for value_and_grad(has_aux=True)."""
         # profile attribution: forward ops read `jvp(model)`, backward ops
         # `transpose(jvp(model))`, the forward recomputed under remat
         # `rematted_computation`
         with jax.named_scope("model"):
-            return model.loss(params, batch, **loss_kw)
+            return model.loss(params, batch, with_stats=True, **loss_kw)
+
+    def loss_fn(params, batch: Batch):
+        return loss_stats_fn(params, batch)[0]
 
     def _split_micro(batch, acc):
         def split(x):
@@ -241,12 +251,15 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         return jax.tree_util.tree_map(split, batch)
 
     def grads_fn(params, batch: Batch):
-        """(loss, grads), microbatched over comm.accum_steps (C3: large
-        global batches at bounded activation memory). Gradients here are
-        UNREDUCED (local); used by gspmd (partitioner reduces) and by the
-        mlsl accum_steps == 1 path (engine reduces at end)."""
+        """(loss, grads, stats), microbatched over comm.accum_steps (C3:
+        large global batches at bounded activation memory). Gradients here
+        are UNREDUCED (local); used by gspmd (partitioner reduces) and by
+        the mlsl accum_steps == 1 path (engine reduces at end). The model's
+        stats are reported without accumulation only."""
         if comm.accum_steps <= 1:
-            return jax.value_and_grad(loss_fn)(params, batch)
+            (loss, stats), grads = jax.value_and_grad(
+                loss_stats_fn, has_aux=True)(params, batch)
+            return loss, grads, stats
         acc = comm.accum_steps
         micro = _split_micro(batch, acc)
         gz = jax.tree_util.tree_map(
@@ -262,11 +275,11 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         (gsum, lsum), _ = jax.lax.scan(body, (gz, jnp.zeros(())), micro)
         grads = jax.tree_util.tree_map(
             lambda g, pp: (g / acc).astype(pp.dtype), gsum, params)
-        return lsum / acc, grads
+        return lsum / acc, grads, {}
 
     if comm.mode == "gspmd":
         def train_step(state: TrainState, batch: Batch):
-            loss, grads = grads_fn(state.params, batch)
+            loss, grads, stats = grads_fn(state.params, batch)
             grads, gnorm = opt_lib.clip_by_global_norm(grads, grad_clip)
             if comm.prioritize:
                 # barrier-chain only (fuse=False): under GSPMD the reductions
@@ -282,7 +295,7 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
             new = TrainState(params=params, opt_state=opt_state,
                              step=state.step + 1,
                              comm_residuals=state.comm_residuals)
-            return new, {"loss": loss, "grad_norm": gnorm}
+            return new, {"loss": loss, "grad_norm": gnorm, **stats}
         return train_step
 
     assert comm.mode == "mlsl", comm.mode
@@ -431,10 +444,11 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         # ops carry the layer's scope: `model`, `comm/` (the engine) and
         # `optim/` (everything from the reduced gradients to the new
         # parameters)
+        stats = {}
         if comm.accum_steps > 1:
             loss, grads, residuals = accum_reduce(params, batch, residuals)
         else:
-            loss, grads = grads_fn(params, batch)
+            loss, grads, stats = grads_fn(params, batch)
             grads, residuals = engine.reduce(grads, residuals)
             # the engine reduces in f32; hand the optimizer the parameters'
             # dtype, as the gspmd step does. The barrier keeps that cast:
@@ -447,10 +461,11 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
         with jax.named_scope("optim/clip"):
             grads, gnorm = clip_grads(grads, grad_clip)
         loss = jax.lax.pmean(loss, data_axes)
+        stats = _reduce_stats(stats, data_axes)
         with jax.named_scope("optim/update"):
             params, opt_state = optimizer.update(grads, opt_state, params,
                                                  step)
-        return params, opt_state, residuals, loss, gnorm
+        return params, opt_state, residuals, loss, gnorm, stats
 
     grad_treedef = engine.plan.buckets.treedef
     if tp_axis is None:
@@ -485,15 +500,29 @@ def make_train_step(model: Model, optimizer: opt_lib.Optimizer, mesh: Mesh,
             in_specs=(params_specs, opt_specs, replicated, res_spec,
                       batch_in_specs),
             out_specs=(params_specs, opt_specs, res_spec, replicated,
-                       replicated),
+                       replicated, replicated),
             axis_names=set(manual_axes), check_vma=False,
         )(state.params, state.opt_state, state.step, residuals, batch)
-        params, opt_state, residuals, loss, gnorm = out
+        params, opt_state, residuals, loss, gnorm, stats = out
         new = TrainState(params=params, opt_state=opt_state,
                          step=state.step + 1, comm_residuals=residuals)
-        return new, {"loss": loss, "grad_norm": gnorm}
+        return new, {"loss": loss, "grad_norm": gnorm, **stats}
 
     return train_step
+
+
+def _reduce_stats(stats: dict, axes) -> dict:
+    """The model's per-chip statistics over the data axes: row counts
+    summed, maxima the largest, the aux loss averaged as the loss is."""
+    out = {}
+    for k, v in stats.items():
+        if k.startswith("moe/max_"):
+            out[k] = jax.lax.pmax(v, axes)
+        elif k == "moe/aux":
+            out[k] = jax.lax.pmean(v, axes)
+        else:
+            out[k] = jax.lax.psum(v, axes)
+    return out
 
 
 def _is_pd(x):

@@ -80,6 +80,7 @@ def test_full_config_matches_assignment(arch):
         "recurrentgemma-2b": (26, 2560, 256000),
         "grok-1-314b": (64, 6144, 131072),
         "whisper-small": (12, 768, 51865), "deepseek-7b": (30, 4096, 102400),
+        "deepseek-v2-lite": (27, 2048, 102400),
     }[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.vocab) == expected
     assert cfg.source
@@ -88,6 +89,11 @@ def test_full_config_matches_assignment(arch):
         assert cfg.moe.dense_residual_ff > 0
     if arch == "grok-1-314b":
         assert cfg.moe.n_experts == 8 and cfg.moe.top_k == 2
+    if arch == "deepseek-v2-lite":
+        assert cfg.moe.n_experts == 64 and cfg.moe.top_k == 6
+        assert cfg.moe.shared_ff == 2 * 1408 and cfg.moe.dropless
+        assert cfg.lead_layers == ("mla",) and cfg.pattern_repeats == 26
+        assert cfg.mla.q_lora_rank == 0 and cfg.mla.yarn.factor == 40
     if arch == "mamba2-2.7b":
         assert cfg.ssm.d_state == 128 and cfg.attn is None
     if arch == "recurrentgemma-2b":

@@ -8,6 +8,8 @@ never while a module is imported: only one process may load the TPU
 library, and the suite runs under several workers.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -155,3 +157,71 @@ def test_kernel_compiles_at_bucket_sizes(one_chip, kernel, n_blocks, dtype):
     compiled = getattr(quant8, kernel).lower(*args, **static).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_dsv2lite_cell_step_compiles_for_v5e(topo):
+    """The dsv2lite-l7e8.moe-bf16.1chip cell's train step (2 x 4096 tokens,
+    flat mlsl, bf16 wire, kv_chunk 1024) compiled for one described v5e
+    chip: its buffers fit the chip's 16 GB, and the grouped-matmul kernels
+    of the dropless MoE are Mosaic custom calls in it (forward, the rows'
+    gradient and the weights' gradient)."""
+    import json
+    import pathlib
+    import re
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import registry
+    from repro.core import planner as pl
+    from repro.models.transformer import Batch, Model
+    from repro.optim import optimizers as opt_lib
+    from repro.train import trainer as tr
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmarks/chip/configs/dsv2lite-l7e8.json")
+                     .read_text())
+    traffic = json.loads((root / "benchmarks/chip/traffic/"
+                          "moe-bf16-b2s4k.json").read_text())
+    rep = dict(cfg["program"]["replace"])
+    moe = dataclasses.replace(registry.get_config(cfg["program"]["arch"]).moe,
+                              held=tuple(rep.pop("moe")["held"]))
+    mc = dataclasses.replace(registry.get_config(cfg["program"]["arch"]),
+                             moe=moe, **rep)
+    model = Model(mc)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    planner = pl.Planner(mesh=mesh)
+    opt = cfg["optimizer"]
+    optimizer = opt_lib.make_optimizer(
+        opt["name"], opt["lr"], b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
+        weight_decay=opt["weight_decay"])
+    comm = tr.CommConfig(**traffic["comm"])
+    engine = tr.make_comm_engine(model, mesh, planner, comm)
+    state_sh = tr.state_shardings(planner, model, optimizer, engine)
+    step = jax.jit(tr.make_train_step(model, optimizer, mesh, planner, comm,
+                                      grad_clip=opt["clip"], engine=engine),
+                   out_shardings=(state_sh, NamedSharding(mesh, P())),
+                   donate_argnums=0)
+    shapes = jax.eval_shape(lambda: tr.make_train_state(
+        model, optimizer, jax.random.PRNGKey(0), engine=engine))
+    state = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        shapes, state_sh)
+    B, S = traffic["batch"], traffic["seq"]
+    tok = jax.ShapeDtypeStruct((B, S), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    with jax.set_mesh(mesh):
+        compiled = step.lower(state, Batch(tokens=tok, labels=tok)).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < 16e9, (total, str(mem))
+    calls = re.findall(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())
+    names = [re.sub(r"\.\d+$", "", c) for c in calls]
+    # moe_gmm: the three forward products, recomputed, and the rows'
+    # gradients; moe_tgmm: the weights' gradients
+    assert names.count("moe_gmm") >= 2 and "moe_tgmm" in names, names
