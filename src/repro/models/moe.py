@@ -6,10 +6,17 @@ Three dispatch implementations. `moe_dropless` computes every routed row:
     that land on the experts this chip holds (MoEConfig.held) are sorted by
     expert and run through grouped matmuls (kernels/gmm.py) over the real
     group sizes, then gate-weighted and summed back per token, plus the
-    shared experts. The router stays n_experts wide; buffers are static at
-    the worst case of T * top_k rows, the grouped matmuls' work follows the
-    routed rows. Its scopes name the stages: moe/route, moe/dispatch,
-    moe/experts, moe/combine, moe/shared.
+    shared experts. The router stays n_experts wide. The dispatch, expert
+    and combine buffers are static at R rows (`held_row_bound`: twice the
+    held experts' uniform share of the T * top_k assignments): the held
+    ones are the first rows of the sorted order, and where they outrun R
+    the further ones run in more chunks of R rows, so no routing drops a
+    row. Only combine's forward gather and dispatch's way back read all
+    T * top_k assignments, from the R rows. Where R would not be less
+    than T * top_k (all experts held) the buffers are T * top_k rows. The
+    grouped matmuls' work follows the routed rows either way. Its scopes
+    name the stages: moe/route, moe/dispatch, moe/experts, moe/combine,
+    moe/shared.
 
 The other two are capacity-based (GShard semantics, overflow tokens dropped
 from the expert path but preserved by the residual):
@@ -66,11 +73,12 @@ def moe_defs(d_model: int, m: MoEConfig, dtype) -> dict:
 
 def zero_stats(m: MoEConfig | None) -> dict:
     """The per-layer statistics a block reports, at zero: the aux loss,
-    and on the dropless path the rows routed to held experts and the
-    largest held group."""
+    and on the dropless path the rows routed to held experts, the largest
+    held group and whether the layer ran on R rows (bounded_layers)."""
     z = jnp.zeros((), jnp.float32)
     if m is not None and m.dropless:
-        return {"aux": z, "held_rows": z, "max_expert_rows": z}
+        return {"aux": z, "held_rows": z, "max_expert_rows": z,
+                "bounded_layers": z}
     return {"aux": z}
 
 
@@ -81,6 +89,22 @@ def merge_stats(total: dict, layer: dict) -> dict:
         out[k] = jnp.maximum(out[k], v) if k.startswith("max_") \
             else out[k] + v
     return out
+
+
+# the dropless layer's bounded buffers hold this many times the held
+# experts' share of the assignments under uniform routing
+HELD_HEADROOM = 2
+
+
+def held_row_bound(n_tokens: int, m: MoEConfig) -> int:
+    """R, the rows of the dropless layer's bounded buffers for n_tokens
+    tokens: HELD_HEADROOM times the held experts' uniform share of the
+    n_tokens * top_k assignments, in whole grouped-matmul row tiles, and
+    never more than n_tokens * top_k."""
+    tk = n_tokens * m.top_k
+    share = -(-HELD_HEADROOM * tk * m.held_range[1] // m.n_experts)
+    tile = gmm_lib.TILE_M
+    return min(tk, -(-share // tile) * tile)
 
 
 def capacity(n_tokens: int, m: MoEConfig) -> int:
@@ -126,11 +150,21 @@ def seq_aux(probs, ids, m: MoEConfig, batch: int):
     return jnp.mean(jnp.sum(ce * p_mean, axis=-1))
 
 
+def _take_or_zero(a, idx):
+    """a[idx] where 0 <= idx < len(a), else zero: the rows of a chunk of
+    the sorted assignments, read back by all T * k of them."""
+    n = a.shape[0]
+    zero = jnp.zeros((1,) + a.shape[1:], a.dtype)
+    return jnp.concatenate([a, zero])[
+        jnp.where((idx >= 0) & (idx < n), idx, n)]
+
+
 @jax.custom_vjp
 def _dispatch(xf, token, inv):
-    """Rows xf[token] (token: the row of each sorted assignment); the way
-    back gathers each assignment's cotangent through the inverse
-    permutation `inv` and sums a token's k of them (no scatter)."""
+    """Rows xf[token] (token: the row of each sorted assignment, all of
+    them or a chunk's); the way back gathers each assignment's cotangent
+    through the inverse permutation `inv` (shifted to the chunk's first
+    row: zero outside it) and sums a token's k of them (no scatter)."""
     return xf[token]
 
 
@@ -140,8 +174,9 @@ def _dispatch_fwd(xf, token, inv):
 
 def _dispatch_bwd(res, g):
     inv, T = res
-    k = g.shape[0] // T
-    dx = jnp.sum(g[inv].reshape(T, k, -1).astype(jnp.float32), axis=1)
+    k = inv.shape[0] // T
+    g = g[inv] if g.shape[0] == inv.shape[0] else _take_or_zero(g, inv)
+    dx = jnp.sum(g.reshape(T, k, -1).astype(jnp.float32), axis=1)
     return dx.astype(g.dtype), None, None
 
 
@@ -166,11 +201,122 @@ def _permute_bwd(res, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+@jax.custom_vjp
+def _combine(ys, gate, order, inv):
+    """sum_k gate[t, k] * ys[row of (t, k)] in float32, (T, d), for the
+    rows ys of a chunk `order` of the sorted assignments, `inv` their
+    inverse permutation shifted to the chunk's first row; an assignment
+    outside the chunk reads zero. The way back builds ys's cotangent on
+    the chunk's rows alone, and the gate's from them through `inv`."""
+    T, K = gate.shape
+    yf = _take_or_zero(ys, inv).reshape(T, K, -1)
+    return jnp.sum(yf.astype(jnp.float32) * gate[..., None], axis=1)
+
+
+def _combine_fwd(ys, gate, order, inv):
+    return _combine(ys, gate, order, inv), (ys, gate, order, inv)
+
+
+def _combine_bwd(res, g):
+    ys, gate, order, inv = res
+    T, K = gate.shape
+    g_rows = g[order // K]                                  # (R, d) f32
+    d_ys = (g_rows * gate.reshape(-1)[order][:, None]).astype(ys.dtype)
+    d_gate = _take_or_zero(
+        jnp.sum(g_rows * ys.astype(jnp.float32), axis=-1), inv)
+    return d_ys, d_gate.reshape(T, K), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _routed(rows, act, spec, lo, xf, w1, w2, w3, weights, held, order, inv,
+            sizes):
+    """The routed experts' part of the layer, float32 (T, d), on buffers
+    of the sorted assignments lo .. lo + rows: all T * k of them (lo 0),
+    or a chunk of R (`order` padded to whole chunks)."""
+    T, K = held.shape
+    with jax.named_scope("moe/dispatch"):
+        if rows < T * K:
+            order = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+            ends = jnp.clip(jnp.cumsum(sizes) - lo, 0, rows)
+            sizes = jnp.diff(ends, prepend=0)               # the chunk's
+            inv = inv - lo
+        xs = _dispatch(xf, order // K, inv)                 # (rows, d)
+    with jax.named_scope("moe/experts"):
+        f = common.act_fn(act)
+        h = f(gmm_lib.grouped_matmul(xs, w1, sizes, spec)) \
+            * gmm_lib.grouped_matmul(xs, w3, sizes, spec)
+        ys = gmm_lib.grouped_matmul(h, w2, sizes, spec)   # pad rows 0
+    with jax.named_scope("moe/combine"):
+        gate = jnp.where(held, weights, 0.0)
+        if rows < T * K:
+            return _combine(ys, gate, order, inv)
+        yf = _permute(ys, inv, order).reshape(T, K, -1)
+        return jnp.sum(yf.astype(jnp.float32) * gate[..., None], axis=1)
+
+
+def _chunks(sizes, rows):
+    """How many chunks of `rows` sorted assignments hold the held ones."""
+    return -(-jnp.sum(sizes) // rows)
+
+
+def _padded(order, rows):
+    pad = -order.shape[0] % rows
+    return jnp.pad(order, (0, pad)) if pad else order
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _chunked(rows, act, spec, xf, w1, w2, w3, weights, held, order, inv,
+             sizes):
+    """`_routed` over the sorted assignments in chunks of R = rows: the
+    first chunk, then a loop over any further chunks the held assignments
+    fill, which takes no step where they fit in R. Every buffer is R rows
+    and no routing drops a row. Its residuals are its inputs: the way back
+    recomputes each chunk, adding a further chunk's gradients in float32
+    to the first's, rounded to their dtype once per chunk."""
+    run = functools.partial(_routed, rows, act, spec)
+    diff, rest = (xf, w1, w2, w3, weights), (held, _padded(order, rows),
+                                             inv, sizes)
+    return jax.lax.fori_loop(
+        1, _chunks(sizes, rows),
+        lambda c, y: y + run(c * rows, *diff, *rest), run(0, *diff, *rest))
+
+
+def _chunked_fwd(rows, act, spec, *args):
+    return _chunked(rows, act, spec, *args), args
+
+
+def _chunked_bwd(rows, act, spec, res, g):
+    xf, w1, w2, w3, weights, held, order, inv, sizes = res
+    diff, rest = (xf, w1, w2, w3, weights), (held, _padded(order, rows),
+                                             inv, sizes)
+
+    def grads(lo):
+        def f(*d):
+            return _routed(rows, act, spec, lo, *d, *rest)
+        return jax.vjp(f, *diff)[1](g)
+
+    def add(c, acc):
+        return tuple((a.astype(jnp.float32) + b.astype(jnp.float32)).astype(
+            a.dtype) for a, b in zip(acc, grads(c * rows)))
+
+    return (*jax.lax.fori_loop(1, _chunks(sizes, rows), add, grads(0)),
+            None, None, None, None)
+
+
+_chunked.defvjp(_chunked_fwd, _chunked_bwd)
+
+
 def moe_dropless(p: dict, x: jax.Array, m: MoEConfig, *, act: str = "silu",
                  gmm_backend: str = "ragged"):
     """Dropless MoE over the held experts. x (B, S, d) -> (y, stats):
-    stats = {aux, held_rows, max_expert_rows} of this layer. gmm_backend:
-    kernels/gmm.py's (the train step picks the compiled kernels on a TPU:
+    stats = {aux, held_rows, max_expert_rows, bounded_layers} of this
+    layer. Its buffers hold R = held_row_bound(B * S, m) rows; the held
+    assignments fit in one such chunk (bounded_layers 1) or run over more
+    (bounded_layers 0). Where R = T * top_k the buffers hold every
+    assignment and bounded_layers is 0. gmm_backend: kernels/gmm.py's
+    (the train step picks the compiled kernels on a TPU:
     trainer.make_train_step)."""
     B, S, d = x.shape
     T, K = B * S, m.top_k
@@ -184,27 +330,28 @@ def moe_dropless(p: dict, x: jax.Array, m: MoEConfig, *, act: str = "silu",
         held = (local >= 0) & (local < count)               # (T, K)
         key = jnp.where(held, local, count).reshape(-1)     # (T*K,)
         # sorts and a dense count, no scatter: their cost is the same for
-        # any routing
+        # any routing; the sort is stable and non-held keys sort last, so
+        # the held assignments are order[:sum(sizes)]
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inv = jnp.argsort(order).astype(jnp.int32)
         sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=key.dtype),
                         axis=0, dtype=jnp.int32)
-        xs = _dispatch(xf, order // K, inv)                 # (T*K, d)
-    with jax.named_scope("moe/experts"):
-        f = common.act_fn(act)
-        h = f(gmm_lib.grouped_matmul(xs, p["w1"], sizes, spec)) \
-            * gmm_lib.grouped_matmul(xs, p["w3"], sizes, spec)
-        ys = gmm_lib.grouped_matmul(h, p["w2"], sizes, spec)  # pad rows 0
+    rows = held_row_bound(T, m)
+    args = (xf, p["w1"], p["w2"], p["w3"], weights, held, order, inv, sizes)
+    if rows == T * K:
+        y = _routed(rows, act, spec, 0, *args)
+        bounded = jnp.zeros((), jnp.float32)
+    else:
+        y = _chunked(rows, act, spec, *args)
+        bounded = (_chunks(sizes, rows) <= 1).astype(jnp.float32)
     with jax.named_scope("moe/combine"):
-        yf = _permute(ys, inv, order).reshape(T, K, d)
-        gate = jnp.where(held, weights, 0.0)
-        y = jnp.sum(yf.astype(jnp.float32) * gate[..., None], axis=1)
         y = y.astype(x.dtype).reshape(B, S, d)
     if "shared" in p:
         with jax.named_scope("moe/shared"):
             y = y + mlp.mlp_apply(p["shared"], x, act=act)
     stats = {"aux": aux, "held_rows": jnp.sum(sizes).astype(jnp.float32),
-             "max_expert_rows": jnp.max(sizes).astype(jnp.float32)}
+             "max_expert_rows": jnp.max(sizes).astype(jnp.float32),
+             "bounded_layers": bounded}
     return y, stats
 
 

@@ -2,8 +2,10 @@
 grouped-matmul kernel, at small widths on seeded random weights: against
 a masked-dense reference in the forward pass and in gradients, under a
 routing forced onto one expert, summed over the shares of an
-expert-parallel deployment, and the sequence-wise aux loss against its
-formula."""
+expert-parallel deployment, the sequence-wise aux loss against its
+formula, and the buffers bounded at R rows against those of all T * k
+assignments, bit for bit, with the chunked fallback where the held
+assignments outrun R."""
 
 import dataclasses
 
@@ -12,9 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import registry
 from repro.configs.base import MoEConfig
 from repro.kernels import gmm
 from repro.models import common, mlp, moe
+from repro.models.transformer import Batch, Model
 
 D, FF = 32, 16
 DSV2 = MoEConfig(n_experts=64, top_k=6, d_ff=FF, shared_ff=2 * FF,
@@ -77,6 +81,107 @@ def test_dropless_matches_masked_dense(backend, held):
     for a, b in zip(jax.tree_util.tree_leaves(g_ours),
                     jax.tree_util.tree_leaves(g_ref)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def _all_rows(monkeypatch, m: MoEConfig):
+    """Buffers of all T * k rows from here on: R = T * k for any T."""
+    monkeypatch.setattr(moe, "HELD_HEADROOM", m.n_experts)
+
+
+@pytest.mark.parametrize("backend", ["ragged", "interpret"])
+@pytest.mark.parametrize("held", [(0, 8), (8, 8)])
+def test_bounded_buffers_bit_identical_to_all_rows(backend, held,
+                                                   monkeypatch):
+    """On R = T * k / 4 rows (twice the uniform share of 8 of 64 experts)
+    the layer's output and the gradients of x and of every parameter are
+    those of the buffers of all T * k rows, bit for bit: the held
+    assignments are the sorted order's first rows, and every row past them
+    is zero on both paths."""
+    m = dataclasses.replace(DSV2, held=held)
+    p = _params(m, jax.random.PRNGKey(12))
+    x = _x(13, S=512)
+    T = x.shape[0] * x.shape[1]
+    assert moe.held_row_bound(T, m) == T * m.top_k // 4
+
+    def run():
+        def loss(p, x):
+            y, stats = moe.moe_dropless(p, x, m, gmm_backend=backend)
+            return jnp.sum(jnp.sin(y)), (y, stats)
+        (_, (y, stats)), g = jax.jit(jax.value_and_grad(
+            loss, (0, 1), has_aux=True))(p, x)
+        return y, stats, g
+
+    y, stats, g = run()
+    assert float(stats["bounded_layers"]) == 1.0
+    _all_rows(monkeypatch, m)
+    assert moe.held_row_bound(T, m) == T * m.top_k
+    y_all, stats_all, g_all = run()
+    assert float(stats_all["bounded_layers"]) == 0.0
+    assert float(stats["held_rows"]) == float(stats_all["held_rows"])
+    np.testing.assert_array_equal(y, y_all)
+    paths = jax.tree_util.tree_leaves_with_path(g)
+    assert len(paths) == 1 + len(jax.tree_util.tree_leaves(p))
+    for (path, a), b in zip(paths, jax.tree_util.tree_leaves(g_all)):
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+
+
+@pytest.mark.parametrize("backend", ["ragged", "interpret"])
+def test_fallback_when_held_assignments_outrun_the_bound(backend):
+    """Every token's six slots forced onto the 8 held experts: 6 T held
+    assignments, four times R. The layer runs them in chunks of R rows,
+    drops none, and matches the masked-dense reference in the output and
+    in gradients; bounded_layers reads 0. T * k = 6,000 is no whole
+    number of chunks of R = 1,536, so the last chunk is padded."""
+    m = dataclasses.replace(DSV2, held=(0, 8))
+    p = _params(m, jax.random.PRNGKey(14))
+    x = _x(15, S=500).at[..., 0].set(4.0)
+    p["router"] = p["router"].at[0, :8].add(8.0)
+    T = x.shape[0] * x.shape[1]
+    rows = moe.held_row_bound(T, m)
+    assert rows == 1536 and (T * m.top_k) % rows
+
+    def ours(p, x):
+        return moe.moe_dropless(p, x, m, gmm_backend=backend)
+
+    y, stats = ours(p, x)
+    assert float(stats["held_rows"]) == T * m.top_k > rows
+    assert float(stats["bounded_layers"]) == 0.0
+    np.testing.assert_allclose(y, masked_dense(p, x, m), rtol=1e-5,
+                               atol=1e-5)
+    loss = lambda f: lambda p, x: jnp.sum(jnp.sin(f(p, x)))  # noqa: E731
+    g_ours = jax.grad(loss(lambda p, x: ours(p, x)[0]), (0, 1))(p, x)
+    g_ref = jax.grad(loss(lambda p, x: masked_dense(p, x, m)), (0, 1))(p, x)
+    for a, b in zip(jax.tree_util.tree_leaves(g_ours),
+                    jax.tree_util.tree_leaves(g_ref)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("held,want", [((0, 1), 2.0), (None, 0.0)])
+def test_bounded_layers_counts_the_model_layers(held, want):
+    """The step's counter over a model's MoE layers: both of them on R
+    rows under balanced routing where the chip holds 1 of 4 experts, none
+    where it holds all 4 (R = T * k), and the loss's gradient through the
+    rematerialised scan stays finite."""
+    base = registry.get_smoke_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(base, n_layers=3, remat=True,
+                              moe=dataclasses.replace(base.moe, held=held))
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(16))
+    tok = jax.random.randint(jax.random.PRNGKey(17), (2, 256), 0, cfg.vocab)
+    batch = Batch(tokens=tok, labels=tok)
+    T = tok.size
+    rows = moe.held_row_bound(T, cfg.moe)
+    assert (rows < T * cfg.moe.top_k) == (held is not None)
+
+    def loss(params):
+        return model.loss(params, batch, with_stats=True)
+
+    (value, stats), g = jax.value_and_grad(loss, has_aux=True)(params)
+    assert cfg.pattern_repeats == 2
+    assert float(stats["moe/bounded_layers"]) == want
+    assert float(stats["moe/held_rows"]) <= 2 * rows
+    assert all(bool(jnp.all(jnp.isfinite(x)))
+               for x in jax.tree_util.tree_leaves(g))
 
 
 def test_held_rows_count_the_held_assignments():
